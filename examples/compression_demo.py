@@ -16,7 +16,7 @@ THETA = 0.7
 grad = jax.random.normal(jax.random.PRNGKey(0), (8 * 4096,)) * 0.05
 print(f"gradient: {grad.size} floats = {grad.size * 4 / 1e3:.0f} KB")
 
-# 1. chunked rFFT (TPU: fft4step Pallas kernel — two 64x64 MXU matmuls)
+# 1. chunked rFFT (TPU: fft4step Pallas kernel — 128- and 32-point DFT matmuls)
 freqs, n = cfft.chunked_rfft(grad)
 print(f"1. rFFT -> {freqs.shape} complex bins per chunk")
 

@@ -60,11 +60,7 @@ def axis_sizes(axes: AxisSpec) -> Tuple[int, ...]:
 
 
 def axis_linear_index(axes: AxisSpec):
-    """Row-major linear worker index over one axis or a tuple of axes.
-
-    Equivalent to ``jax.lax.axis_index(tuple)`` but spelled out so it works
-    on every jax generation the repo straddles (0.4.x included).
-    """
+    """Row-major linear worker index over one axis or a tuple of axes."""
     norm = normalize_axes(axes)
     if isinstance(norm, str):
         return jax.lax.axis_index(norm)

@@ -169,8 +169,8 @@ def _compress_stacked(flat: jnp.ndarray, layout, comp, monitor=None):
 
 def _irfft_rows(mean_spectrum: jnp.ndarray, chunk: int) -> jnp.ndarray:
     """(B, max_chunks, f) mean spectrum -> (B, padded_size) time domain."""
-    x = jnp.fft.irfft(mean_spectrum, n=chunk, axis=-1)
-    return x.reshape(mean_spectrum.shape[0], -1).astype(jnp.float32)
+    x = cfft.irfft_rows(mean_spectrum, chunk)
+    return x.reshape(mean_spectrum.shape[0], -1)
 
 
 def _ordered_worker_mean(stacked: jnp.ndarray) -> jnp.ndarray:
@@ -190,6 +190,19 @@ def _ordered_worker_mean(stacked: jnp.ndarray) -> jnp.ndarray:
     return acc * (1.0 / p)
 
 
+def _mean_spectrum(gathered, comp) -> jnp.ndarray:
+    """Mean of the gathered payloads' dense spectra in
+    ``_ordered_worker_mean``'s order, with each worker's kept coefficients
+    scattered onto ONE running spectrum: P gradient-sized spectra do not
+    fit beside a large model's training state."""
+    p = jax.tree_util.tree_leaves(gathered)[0].shape[0]
+    worker = lambda w: jax.tree_util.tree_map(lambda a: a[w], gathered)
+    acc = comp.decompress_spectrum(worker(0))
+    for w in range(1, p):
+        acc = comp.decompress_spectrum(worker(w), into=acc)
+    return acc * (1.0 / p)
+
+
 def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     """Seed exchange: all_gather one payload -> mean reconstruction.
 
@@ -198,8 +211,7 @@ def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     """
     gathered = jax.lax.all_gather(payload, axis)  # leading axis: workers
     if hasattr(comp, "decompress_spectrum"):
-        spectra = jax.vmap(comp.decompress_spectrum)(gathered)
-        mean_spectrum = _ordered_worker_mean(spectra)
+        mean_spectrum = _mean_spectrum(gathered, comp)
         return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
     decompressed = jax.vmap(comp.decompress)(gathered)
     return _ordered_worker_mean(decompressed)
@@ -404,8 +416,7 @@ class SequencedTransport(Transport):
         payload = _compress_stacked(flat, layout, comp, monitor)
         gathered = jax.lax.all_gather(payload, axis)  # ONE collective
         if hasattr(comp, "decompress_spectrum"):
-            spectra = jax.vmap(comp.decompress_spectrum)(gathered)
-            mean = _ordered_worker_mean(spectra)  # (B, max_chunks, f)
+            mean = _mean_spectrum(gathered, comp)  # (B, max_chunks, f)
             return bucketing.unstack_buckets(
                 _irfft_rows(mean, layout.chunk), layout)
         recon = jax.vmap(comp.decompress_stacked)(gathered)  # (W, B, padded)
@@ -511,7 +522,7 @@ class HierarchicalTransport(Transport):
         rows = bucketing.stack_buckets(flat, layout)  # (B, padded)
         if hasattr(comp, "decompress_spectrum"):
             x3 = rows.reshape(layout.n_buckets, -1, layout.chunk)
-            spec = jnp.fft.rfft(x3, axis=-1)  # DENSE spectra — no top-k
+            spec = cfft.rfft_rows(x3)  # DENSE spectra — no top-k
             summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), local_ax)
             node_mean = bucketing.unstack_buckets(
                 _irfft_rows((summed[0] + 1j * summed[1]) * inv_l, layout.chunk),
@@ -525,8 +536,7 @@ class HierarchicalTransport(Transport):
         node_payload = _compress_stacked(node_mean, layout, comp, monitor)
         gathered = jax.lax.all_gather(node_payload, node_ax)
         if hasattr(comp, "decompress_spectrum"):
-            spectra = jax.vmap(comp.decompress_spectrum)(gathered)
-            mean = _ordered_worker_mean(spectra)
+            mean = _mean_spectrum(gathered, comp)
             return bucketing.unstack_buckets(
                 _irfft_rows(mean, layout.chunk), layout)
         recon = jax.vmap(comp.decompress_stacked)(gathered)

@@ -309,9 +309,10 @@ class FFTCompressor:
     def compress(self, x_flat: jnp.ndarray, key=None) -> FFTPayload:
         return self._backend.compress(self.config, x_flat)
 
-    def decompress_spectrum(self, payload: FFTPayload) -> jnp.ndarray:
-        """Payload -> dense complex spectrum (c, chunk//2+1)."""
-        return self._backend.decompress_spectrum(payload)
+    def decompress_spectrum(self, payload: FFTPayload, into=None) -> jnp.ndarray:
+        """Payload -> dense complex spectrum (c, chunk//2+1), added onto the
+        dense spectrum ``into`` when one is given."""
+        return self._backend.decompress_spectrum(payload, into)
 
     def decompress(self, payload: FFTPayload) -> jnp.ndarray:
         return self._backend.decompress(payload)

@@ -32,9 +32,14 @@ Two ways to fit ``eps``:
   within ×2.  Both are exposed; the hot path uses the closed form.
 
 Everything here is pure ``jnp`` and jit-compatible with dynamic ``min``/``max``
-(the fit is branch-free math / a bounded ``while_loop``).  The Pallas kernel in
-``repro.kernels.range_quant`` implements the same encode/decode for the TPU hot
-path and is checked against this module.
+(the fit is branch-free math / a bounded ``while_loop``).  Encode and decode
+are :func:`encode_math` / :func:`decode_math`, which the Pallas kernels
+(``repro.kernels.range_quant``, ``fused_compress``, ``fused_decompress``)
+call in-register: one definition, built only from compares, additions,
+multiplications and bit operations.  A TPU's division, ``log2`` and ``exp2``
+are approximations that XLA and Mosaic implement differently, so a code
+computed with them could differ between the jnp reference and a kernel
+whenever a value sits at a code boundary.
 """
 
 from __future__ import annotations
@@ -53,6 +58,8 @@ __all__ = [
     "fit_quantizer",
     "encode",
     "decode",
+    "encode_math",
+    "decode_math",
     "representable_values",
 ]
 
@@ -111,12 +118,25 @@ class FittedQuantizer:
         return decode(codes, self)
 
 
+def _pow2(q):
+    """2**q as f32 for integer q in [-126, 127], from the exponent bits."""
+    bits = (jnp.asarray(q, jnp.int32) + 127) << 23
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _exponent_mantissa(x):
+    """(biased exponent, mantissa bits) of positive normal f32 values."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits >> 23, bits & 0x7FFFFF
+
+
 def _value_of_index(idx, eps, m_bits):
     """value for 0-based positive index: eps * 2**q * (1 + r/2**m)."""
-    m_scale = 1 << m_bits
-    q = idx // m_scale
-    r = idx % m_scale
-    return eps * jnp.exp2(q.astype(jnp.float32)) * (1.0 + r.astype(jnp.float32) / m_scale)
+    idx = jnp.asarray(idx, jnp.int32)
+    q = idx >> m_bits
+    r = idx & ((1 << m_bits) - 1)
+    return (eps * _pow2(jnp.minimum(q, 127))
+            * (1.0 + r.astype(jnp.float32) * (1.0 / (1 << m_bits))))
 
 
 def solve_eps(vmin, vmax, config: RangeQuantConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -226,52 +246,63 @@ def fit_quantizer(
     return FittedQuantizer(config, eps, p, vmax_rep, vmin_rep)
 
 
-def _encode_magnitude(a, eps, m_bits, max_idx):
-    """0-based index for magnitude ``a`` (≥0); round-to-nearest; clipped."""
+def encode_math(x, eps, p_codes, n_neg, m_bits: int):
+    """f32 values -> int32 codes (0 = zero, 1..P positive, P+1.. negative).
+
+    ``eps``, ``p_codes`` and ``n_neg`` are scalars or arrays broadcasting
+    against ``x`` (one fit per row in the batched kernels).  Magnitudes
+    round to the nearest of their segment's ``2**m`` values (halves up);
+    below ``eps`` to the nearer of 0 and ``eps``; beyond the range they clip.
+    """
     m_scale = 1 << m_bits
+    a = jnp.abs(x)
     safe_a = jnp.maximum(a, eps)
-    # exponent segment: floor(log2(a/eps)); nudge avoids 2.0 -> q=0.9999…
-    q = jnp.floor(jnp.log2(safe_a) - jnp.log2(eps) + 1e-6)
-    seg_base = eps * jnp.exp2(q)
-    r = jnp.round((safe_a / seg_base - 1.0) * m_scale)
-    # r may round up to 2**m: carry into the next exponent segment.
-    carry = r >= m_scale
+    ea, ma = _exponent_mantissa(safe_a)
+    ee, me = _exponent_mantissa(jnp.broadcast_to(eps, safe_a.shape))
+    q = ea - ee - (ma < me).astype(jnp.int32)  # floor(log2(a / eps)) >= 0
+    seg_base = eps * _pow2(jnp.minimum(q, 127))
+    d = (safe_a - seg_base) * float(m_scale)  # exact: seg_base <= a < 2 seg_base
+    r = sum((d >= (j - 0.5) * seg_base).astype(jnp.int32)
+            for j in range(1, m_scale + 1))
+    carry = r >= m_scale  # rounds up into the next segment
     q = jnp.where(carry, q + 1, q)
-    r = jnp.where(carry, 0.0, r)
-    idx = (q * m_scale + r).astype(jnp.int32)
-    # below-eps values: nearest of {0, eps} in linear space
+    r = jnp.where(carry, 0, r)
+    idx = q * m_scale + r
     idx = jnp.where(a < eps, jnp.where(a * 2.0 >= eps, 0, -1), idx)
-    return jnp.clip(idx, -1, max_idx - 1)  # -1 encodes "zero"
+    p = jnp.asarray(p_codes).astype(jnp.int32)
+    idx_pos = jnp.clip(idx, -1, p - 1)  # -1 encodes "zero"
+    idx_neg = jnp.clip(idx, -1, jnp.maximum(jnp.asarray(n_neg).astype(
+        jnp.int32), 1) - 1)
+    return jnp.where(
+        x >= 0,
+        jnp.where(idx_pos < 0, 0, idx_pos + 1),
+        jnp.where(idx_neg < 0, 0, p + idx_neg + 1),
+    )
+
+
+def decode_math(codes, eps, p_codes, m_bits: int):
+    """int codes -> f32 values; inverse of :func:`encode_math`."""
+    c = jnp.asarray(codes).astype(jnp.int32)
+    p = jnp.asarray(p_codes).astype(jnp.int32)
+    is_pos = (c >= 1) & (c <= p)
+    idx = jnp.maximum(jnp.where(is_pos, c - 1, c - p - 1), 0)
+    mag = _value_of_index(idx, eps, m_bits)
+    return jnp.where(c == 0, 0.0, jnp.where(is_pos, mag, -mag))
 
 
 def encode(x: jnp.ndarray, quant: FittedQuantizer) -> jnp.ndarray:
     """float32 -> N-bit codes (stored in the smallest unsigned dtype)."""
     cfg = quant.config
-    x = x.astype(jnp.float32)
-    pos = x >= 0
-    a = jnp.abs(x)
     n_neg = cfg.n_codes - 1 - quant.p_codes
-    idx_pos = _encode_magnitude(a, quant.eps, cfg.m_bits, quant.p_codes)
-    idx_neg = _encode_magnitude(a, quant.eps, cfg.m_bits, jnp.maximum(n_neg, 1))
-    code = jnp.where(
-        pos,
-        jnp.where(idx_pos < 0, 0, idx_pos + 1),
-        jnp.where(idx_neg < 0, 0, quant.p_codes + idx_neg + 1),
-    )
+    code = encode_math(x.astype(jnp.float32), quant.eps, quant.p_codes, n_neg,
+                       cfg.m_bits)
     return code.astype(cfg.code_dtype)
 
 
 def decode(codes: jnp.ndarray, quant: FittedQuantizer) -> jnp.ndarray:
     """N-bit codes -> float32."""
-    cfg = quant.config
-    c = codes.astype(jnp.int32)
-    is_zero = c == 0
-    is_pos = (c >= 1) & (c <= quant.p_codes)
-    idx = jnp.where(is_pos, c - 1, c - quant.p_codes - 1)
-    idx = jnp.maximum(idx, 0)
-    mag = _value_of_index(idx, quant.eps, cfg.m_bits)
-    val = jnp.where(is_pos, mag, -mag)
-    return jnp.where(is_zero, 0.0, val).astype(jnp.float32)
+    return decode_math(codes, quant.eps, quant.p_codes,
+                       quant.config.m_bits).astype(jnp.float32)
 
 
 def representable_values(quant: FittedQuantizer) -> jnp.ndarray:

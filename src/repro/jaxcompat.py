@@ -1,12 +1,8 @@
-"""Compatibility layer over the jax API renames this repo straddles.
+"""The repo's spellings of mesh construction and partial-manual shard_map.
 
-The codebase targets current jax (``jax.shard_map`` with ``axis_names`` /
-``check_vma``, ``jax.set_mesh``, ``jax.make_mesh(..., axis_types=...)``)
-but must also run on older 0.4.x releases where the same features are spelled
-``jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)``,
-``with mesh:``, and ``jax.make_mesh`` without axis types.  Every call site
-goes through these three wrappers; each dispatches on feature presence, not
-version strings, so intermediate releases behave sensibly.
+Every call site goes through these three helpers, so the choices they make
+(auto-partitioned axes, replication checks off) live in one place.  They
+target the JAX pinned in ``requirements.txt``.
 """
 
 from __future__ import annotations
@@ -19,48 +15,28 @@ __all__ = ["make_auto_mesh", "set_mesh", "shard_map"]
 
 
 def make_auto_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with every axis AUTO (explicit where supported)."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)  # pre-AxisType: axes default to auto
+    """``jax.make_mesh`` with every axis AUTO-partitioned, over all of
+    ``jax.devices()`` in the platform's preferred order."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh.
-
-    ``jax.set_mesh`` where it exists; on older releases ``Mesh`` itself is a
-    context manager with the same scoping behavior.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs, manual_axes: Optional[Sequence[str]] = None):
-    """Partial-manual shard_map without replication checking, both spellings.
+    """Partial-manual shard_map without replication checking.
 
     ``manual_axes`` names the axes stripped inside ``f`` (the rest stay
     AUTO-partitioned).  ``None`` means fully manual — every mesh axis.
-    Replication checking is disabled (``check_vma``/``check_rep``): the
-    compressed reducers return unreplicated per-worker payloads mid-graph.
+    Replication checking is disabled (``check_vma=False``): the compressed
+    reducers return unreplicated per-worker payloads mid-graph.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": False}
-        if manual_axes is not None:
-            kwargs["axis_names"] = frozenset(manual_axes)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {"check_rep": False}
+    kwargs = {"check_vma": False}
     if manual_axes is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-        if auto:
-            kwargs["auto"] = auto
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
+        kwargs["axis_names"] = frozenset(manual_axes)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)

@@ -140,8 +140,9 @@ def _selector_tau(cfg, mag, k: int, sel: str):
         refine_iters=cfg.tau_refine_iters, seed=cfg.selector_seed)
 
 
-def _pallas_tau(cfg, mag2d, k: int, sel: str):
-    """Threshold-kernel dispatch for the pallas backend: (tau (r,1), count).
+def _pallas_select(cfg, mag2d, k: int, sel: str):
+    """Threshold-kernel dispatch for the pallas backend: (tau (r,1), the
+    magnitude plane whose ``mag >= tau`` bins are the kept set).
 
     ``sort`` and ``bisect`` both map to the full bisection kernel — on this
     backend the "sort" selector has always BEEN count-based selection
@@ -150,28 +151,59 @@ def _pallas_tau(cfg, mag2d, k: int, sel: str):
     ``core/selection`` math the reference selector runs (DESIGN.md §16).
     """
     if sel == "sampled":
-        return sampled_threshold.sampled_select(
+        tau, _ = sampled_threshold.sampled_select(
             mag2d, k=k, sample_rate=cfg.sample_rate,
             refine_iters=cfg.tau_refine_iters, seed=cfg.selector_seed)
-    return ops.threshold_select(mag2d, k)
+        return tau, mag2d
+    tau, _ = ops.threshold_select(mag2d, k)
+    if sel == "sort":
+        mag2d = _top_k_ties(mag2d, tau, k)
+    return tau, mag2d
 
 
-def _scatter_spectrum(idx, kept, f_bins: int) -> jnp.ndarray:
-    """Additive scatter of kept coefficients into dense ``(..., f_bins)`` rows.
+def _top_k_ties(mag, tau, k: int):
+    """The magnitude plane with ``lax.top_k``'s choice among exact ties.
+
+    ``tau`` is each row's k-th largest magnitude.  When several bins tie at
+    it, ``mag >= tau`` keeps more than k of them, and the static payload
+    budget would truncate the highest-INDEX kept bins — possibly large
+    coefficients.  ``top_k`` instead keeps the lowest-index tied bins until
+    k are kept; the tied bins past that quota are demoted below every
+    threshold here, so ``mag >= tau`` is exactly the top-k set.
+    """
+    tie = mag == tau
+    quota = k - jnp.sum(mag > tau, axis=-1, keepdims=True)
+    rank = jnp.cumsum(tie.astype(jnp.int32), axis=-1)
+    return jnp.where(tie & (rank > quota), -1.0, mag)
+
+
+def _scatter_spectrum(idx, re, im, f_bins: int, into=None) -> jnp.ndarray:
+    """Additive scatter of kept coefficients (f32 ``re``/``im`` planes) into
+    dense complex ``(..., f_bins)`` rows (zeros, or the dense spectrum
+    ``into``).
 
     Shape-polymorphic over LEADING axes (chunk, bucket, worker — any stack of
     them): the row scatter is defined once over a flattened row axis, so the
     transports' worker-axis ``vmap`` composes with the executor's bucket axis
     without re-tracing per composition (the old per-call ``jnp.zeros`` target
     was rebuilt for every distinct leading shape).  ``.add`` tolerates the
-    code-0/index-0 padding slots of tile- and bucket-padded payloads.
+    code-0/index-0 padding slots of tile- and bucket-padded payloads.  The
+    real and imaginary planes scatter separately: XLA's TPU scatter of f32
+    is ~5x faster than of complex64, and the sums are the same.
     """
-    lead = kept.shape[:-1]
-    k = kept.shape[-1]
+    lead = re.shape[:-1]
+    k = re.shape[-1]
     rows_i = idx.reshape(-1, k)
-    rows_v = kept.reshape(-1, k)
-    zeros = jnp.zeros((rows_v.shape[0], f_bins), rows_v.dtype)
-    out = jax.vmap(lambda row, i, v: row.at[i].add(v))(zeros, rows_i, rows_v)
+    scatter = jax.vmap(lambda row, i, v: row.at[i].add(v))
+
+    def plane(v, base):
+        if base is None:
+            base = jnp.zeros((rows_i.shape[0], f_bins), jnp.float32)
+        return scatter(base.reshape(-1, f_bins), rows_i, v.reshape(-1, k))
+
+    out = jax.lax.complex(
+        plane(re, None if into is None else jnp.real(into)),
+        plane(im, None if into is None else jnp.imag(into)))
     return out.reshape(lead + (f_bins,))
 
 
@@ -253,8 +285,10 @@ class CompressorBackend:
         raise NotImplementedError
 
     # -- decompress --------------------------------------------------------
-    def decompress_spectrum(self, payload) -> jnp.ndarray:
-        """Payload -> dense complex spectrum (..., chunk//2+1).
+    def decompress_spectrum(self, payload, into=None) -> jnp.ndarray:
+        """Payload -> dense complex spectrum (..., chunk//2+1), or that
+        spectrum added onto the dense spectrum ``into`` (the gather
+        transports fold P payloads into one buffer this way).
 
         Shared by every backend: the dequantize+scatter is O(k) work that the
         collectives vmap over the worker axis (comms/transport.py), so it
@@ -266,8 +300,9 @@ class CompressorBackend:
         re, im = payload.re, payload.im
         if payload.quant is not None:
             re, im = q_decode(re, payload.quant), q_decode(im, payload.quant)
-        kept = re.astype(jnp.float32) + 1j * im.astype(jnp.float32)
-        return _scatter_spectrum(payload.idx, kept, payload.chunk // 2 + 1)
+        return _scatter_spectrum(payload.idx, re.astype(jnp.float32),
+                                 im.astype(jnp.float32),
+                                 payload.chunk // 2 + 1, into)
 
     def decompress(self, payload) -> jnp.ndarray:
         spectrum = self.decompress_spectrum(payload)
@@ -279,8 +314,8 @@ class CompressorBackend:
         rows decode to exact zeros, so each row's prefix is bitwise-equal to
         the per-bucket ``decompress``."""
         spectrum = self.decompress_spectrum(payload)  # (B, max_chunks, f)
-        x = jnp.fft.irfft(spectrum, n=payload.chunk, axis=-1)
-        return x.reshape(spectrum.shape[0], -1).astype(jnp.float32)
+        x = cfft.irfft_rows(spectrum, payload.chunk)
+        return x.reshape(spectrum.shape[0], -1)
 
 
 class ReferenceBackend(CompressorBackend):
@@ -368,8 +403,7 @@ class ReferenceBackend(CompressorBackend):
             x2d, c_b = args  # (max_chunks, chunk) rows, true chunk count
             # row-for-row the same transform the looped path runs via
             # cfft.chunked_rfft
-            freqs = jnp.fft.rfft(x2d.astype(jnp.float32),
-                                 axis=-1).astype(jnp.complex64)
+            freqs = cfft.rfft_rows(x2d)
             re_p = jnp.real(freqs).astype(jnp.float32)
             im_p = jnp.imag(freqs).astype(jnp.float32)
             mag = _weighted_magnitude(re_p, im_p, w)
@@ -421,8 +455,8 @@ class ReferenceBackend(CompressorBackend):
 class PallasBackend(CompressorBackend):
     """Fused Pallas kernels on the hot stages, per-stage fallback elsewhere.
 
-    compress:   exact XLA rfft (see module docstring) -> bisection-threshold
-                kernel (quantizer range fit over the kept set) ->
+    compress:   exact XLA rfft (see module docstring) -> threshold kernel
+                (quantizer range fit over the kept set) ->
                 ``fused_compress_pallas`` (threshold+pack+quantize, one VMEM
                 pass) -> slice the 128-lane padding down to the true keep
                 count so the payload layout matches ``reference`` exactly.
@@ -448,7 +482,7 @@ class PallasBackend(CompressorBackend):
         if not cfg.quantize:
             _log_once("pallas compress: quantize=False -> per-stage "
                       "threshold+pack kernels (no fused quantization)")
-            tau, _ = _pallas_tau(cfg, mag, k, sel)
+            tau, mag = _pallas_select(cfg, mag, k, sel)
             mvals, idx = ops.pack_threshold(mag, tau, k)  # width pad_k(k)
             valid = mvals != 0
             re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
@@ -457,30 +491,24 @@ class PallasBackend(CompressorBackend):
                 re_k[:, :k], im_k[:, :k], idx[:, :k].astype(jnp.int16),
                 None, n, cfg.chunk)
 
-        # ONE bisection-threshold pass defines the kept set; its tau is shared
-        # with the fused kernel (no second in-kernel search) so the mask the
-        # kernel packs provably equals the set the quantizer range was fitted
-        # over.  The kernel recomputes the magnitudes IN-REGISTER (that is
-        # the fusion), and a recompute in a different compilation context may
-        # differ by 1 ulp — so the shared tau is placed in the MIDDLE of the
-        # gap between the k-th and (k+1)-th magnitudes, where an ulp of noise
-        # on either side cannot flip the comparison.  (Bitwise ties at the
-        # boundary still truncate under the static budget, as documented on
-        # the slice below.)  Under selector=sampled the same contract holds
-        # with the sampled-bracket tau: count(>= tau) >= k is guaranteed by
-        # the in-kernel clamp, the surplus (a few near-tau values the short
-        # refinement didn't split) truncates index-ascending, and the fit
-        # below covers the full pre-truncation mask — exactly what the
-        # reference selector path fits (DESIGN.md §16).
-        tau_k, _ = _pallas_tau(cfg, mag, k, sel)
-        below = jnp.max(jnp.where(mag < tau_k, mag, 0.0), axis=-1,
-                        keepdims=True)  # largest dropped magnitude (or 0)
-        tau = 0.5 * (tau_k + below)
+        # ONE threshold pass defines the kept set; its tau and the magnitude
+        # plane it was computed on go to the fused kernel (no second
+        # in-kernel search, no in-register recompute), so the mask the kernel
+        # packs provably equals the set the quantizer range was fitted over.
+        # Under selector=sort, exact ties at the k-th magnitude are resolved
+        # as top_k resolves them (``_top_k_ties``).  Under selector=sampled
+        # the same contract holds with the sampled-bracket tau: count(>= tau)
+        # >= k is guaranteed by the in-kernel clamp, the surplus (a few
+        # near-tau values the short refinement didn't split) truncates
+        # index-ascending, and the fit below covers the full pre-truncation
+        # mask — exactly what the reference selector path fits (DESIGN.md
+        # §16).
+        tau, mag = _pallas_select(cfg, mag, k, sel)
         if cfg.range_mode == "fixed":
             lo, hi = cfg.fixed_range
             quant = fit_quantizer(lo, hi, _qcfg(cfg))
         else:
-            mask = mag >= tau  # same set as mag >= tau_k on this plane
+            mask = mag >= tau
             lo = jnp.minimum(jnp.where(mask, re, jnp.inf).min(),
                              jnp.where(mask, im, jnp.inf).min())
             hi = jnp.maximum(jnp.where(mask, re, -jnp.inf).max(),
@@ -488,16 +516,13 @@ class PallasBackend(CompressorBackend):
             quant = fit_quantizer(lo, hi, _qcfg(cfg))
 
         rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
-            re, im, w, quant.eps, quant.p_codes, tau,
+            re, im, mag, quant.eps, quant.p_codes, tau,
             k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
         # slice the tile padding off: payload layout == reference layout.
-        # Residual caveat, bitwise ties ONLY: if j > 0 extra magnitudes equal
-        # the k-th exactly, the mask keeps k+j coefficients, so (a) the range
-        # fit sees j extra values and may differ from reference's k-value
-        # fit, and (b) this slice truncates the highest-INDEX kept slots
-        # (bucketSelect's static-budget semantics, kernels/topk_threshold)
-        # while reference top_k drops by magnitude — code parity is exact
-        # only for tie-free planes (continuous gradient data in practice).
+        # Under the threshold selectors a kept surplus (ties, a sampled tau)
+        # truncates the highest-INDEX kept slots here — bucketSelect's
+        # static-budget semantics, and what the reference's count_compact
+        # does.
         return _payload_cls()(
             rec[:, :k], imc[:, :k], idx[:, :k].astype(jnp.int16),
             quant, n, cfg.chunk)
@@ -506,7 +531,7 @@ class PallasBackend(CompressorBackend):
         """ONE kernel launch for every bucket: all bucket rows ride a single
         grid, and the per-bucket quantizer params become per-ROW planes inside
         the fused kernel (``fused_compress_pallas`` with vector eps/p_codes).
-        The shared mid-gap tau and masked range fit keep codes bitwise-equal
+        The shared tau and masked range fit keep codes bitwise-equal
         to the per-bucket loop (and to the reference backend, slot order
         aside)."""
         sizes = tuple(int(s) for s in sizes)
@@ -514,7 +539,7 @@ class PallasBackend(CompressorBackend):
         c_max = padded // cfg.chunk
         rows = n_buckets * c_max
         x2d = stacked.reshape(rows, cfg.chunk).astype(jnp.float32)
-        freqs = jnp.fft.rfft(x2d, axis=-1).astype(jnp.complex64)
+        freqs = cfft.rfft_rows(x2d)
         re = jnp.real(freqs).astype(jnp.float32)
         im = jnp.imag(freqs).astype(jnp.float32)
         k = _keep_k(cfg)
@@ -525,7 +550,7 @@ class PallasBackend(CompressorBackend):
         if not cfg.quantize:
             _log_once("pallas compress_stacked: quantize=False -> per-stage "
                       "threshold+pack kernels (no fused quantization)")
-            tau, _ = _pallas_tau(cfg, mag, k, sel)
+            tau, mag = _pallas_select(cfg, mag, k, sel)
             mvals, idx = ops.pack_threshold(mag, tau, k)
             valid = mvals != 0
             re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
@@ -536,12 +561,9 @@ class PallasBackend(CompressorBackend):
                 idx[:, :k].astype(jnp.int16).reshape(n_buckets, c_max, k),
                 None, sizes, cfg.chunk)
 
-        # same one-threshold/mid-gap-tau contract as the looped compress,
-        # batched over every bucket's chunks in one threshold-kernel launch
-        tau_k, _ = _pallas_tau(cfg, mag, k, sel)
-        below = jnp.max(jnp.where(mag < tau_k, mag, 0.0), axis=-1,
-                        keepdims=True)
-        tau = 0.5 * (tau_k + below)
+        # same one-threshold contract as the looped compress, batched over
+        # every bucket's chunks in one threshold-kernel launch
+        tau, mag = _pallas_select(cfg, mag, k, sel)
         if cfg.range_mode == "fixed":
             lo = jnp.full((n_buckets,), cfg.fixed_range[0], jnp.float32)
             hi = jnp.full((n_buckets,), cfg.fixed_range[1], jnp.float32)
@@ -566,7 +588,7 @@ class PallasBackend(CompressorBackend):
         eps_rows = jnp.repeat(quant.eps.reshape(n_buckets), c_max)
         p_rows = jnp.repeat(quant.p_codes.reshape(n_buckets), c_max)
         rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
-            re, im, w, eps_rows, p_rows, tau,
+            re, im, mag, eps_rows, p_rows, tau,
             k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
         return _stacked_cls()(
             rec[:, :k].reshape(n_buckets, c_max, k),

@@ -2,28 +2,37 @@
 
 The paper leans on cuFFT.  TPUs have no FFT unit — but the MXU is a 128x128
 systolic matmul array, and Bailey's 4-step factorization turns an N-point DFT
-into sqrt(N) x sqrt(N) DFT *matmuls*:
+into two batches of small DFT *matmuls*.  With N = 32 * 128, input bin
+k = k1 + 32*k2 and output sample n = 128*a + b:
 
-    view x as a (64, 64) matrix  xm[n1, n2] = x[n1*64 + n2]
-    A  = F64 @ xm                     (DFT along columns)        [stage 1]
-    B  = A * W,  W[k1,n2] = w^(k1*n2) (twiddle, elementwise)     [stage 2]
-    Xm = B @ F64^T                    (DFT along rows)           [stage 3]
-    X[k2*64 + k1] = Xm[k1, k2]        (transpose read-out)       [stage 4]
+    S[k1, k2] = X[k1 + 32*k2]          (32, 128) input view          [stage 0]
+    C = S @ F128                       (DFT along k2)                [stage 1]
+    D = C * T,  T[k1, b] = w^(k1*b)    (twiddle, elementwise)        [stage 2]
+    O = F32 @ D                        (DFT along k1)                [stage 3]
+    x[128*a + b] = O[a, b]             (row-major read-out)
 
-Complex arithmetic is carried as separate real/imag planes (the MXU is real):
-stage 1 on a real input costs 2 real 64x64 matmuls, stage 3 costs 4 — six
-64x64x(64*B) matmuls per block of B chunks, batched along columns/rows so the
-MXU sees well-shaped (64, 64*B) operands.
+The factor order is chosen for the TPU's (8, 128) vreg tiling: every matrix
+is 128 lanes wide, and the output O is the transformed chunk in row-major
+order, so no stage reshapes or transposes inside the kernel (Mosaic cannot
+lower the 64-lane 3-D views a 64 x 64 split needs).  Only the input view is
+a transpose; the fused decompress kernel builds it for free in its scatter,
+and the standalone kernel takes it from XLA.
+
+Complex arithmetic is carried as separate real/imag planes stacked along
+sublanes ([re; im], (64, 128)) so that each stage is one or two real
+matmuls: stage 1 multiplies the stack by F128's real and imaginary parts,
+stage 3 multiplies the stacked twiddled planes by the block matrix
+[[F32re, -F32im], [F32im, F32re]].
 
 Napkin math (why this beats a "ported" radix-2 FFT on TPU): 4-step does
-~6*2*64^3*B = 3.1 MFLOP per 4096-chunk vs ~0.25 MFLOP for radix-2 — 12x more
-FLOPs — but runs on the MXU at 197 TFLOP/s(bf16)/~50(f32) with zero
-shuffle/bit-reverse ops, vs the VPU's ~4 TFLOP/s with heavy lane crossings.
-Net ≳ 4x, and the chunk never leaves VMEM.
+2*(2*64*128*128 + 64*64*128) = 5.2 MFLOP per 4096-chunk vs ~0.25 MFLOP for
+radix-2 — 20x more FLOPs — but runs on the MXU with zero shuffle/bit-reverse
+ops, vs the VPU's ~4 TFLOP/s with heavy lane crossings, and the chunk never
+leaves VMEM.
 
-The inverse uses conj twiddles + 1/N.  ``rfft`` semantics (first 2049 bins)
-are applied by the ops.py wrapper; the kernel produces/consumes the full
-4096-bin spectrum.
+The inverse uses conjugate factors and folds 1/N into stage 3.  ``rfft``
+semantics (first 2049 bins) are applied by the ops.py wrapper; the kernel
+produces/consumes the full 4096-bin spectrum.
 """
 
 from __future__ import annotations
@@ -38,78 +47,60 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
-__all__ = ["fft4096_pallas", "apply_4step", "CHUNK", "N1", "N2"]
+__all__ = ["fft4096_pallas", "dft_rows", "dft_constants", "CHUNK", "N1", "N2"]
 
 CHUNK = 4096
-N1 = 64
-N2 = 64
+N1 = 32   # sublane factor (k1, a)
+N2 = 128  # lane factor (k2, b)
 
 
 @functools.lru_cache(maxsize=4)
-def _dft_constants(inverse: bool):
-    """(F64_re, F64_im, W_re, W_im) as float32 numpy arrays."""
-    sign = 2.0 if inverse else -2.0
-    k = np.arange(N1)[:, None]
-    n = np.arange(N1)[None, :]
-    f = np.exp(sign * 1j * np.pi * k * n / N1)
-    k1 = np.arange(N1)[:, None]
-    n2 = np.arange(N2)[None, :]
-    w = np.exp(sign * 1j * np.pi * k1 * n2 / CHUNK)  # w^(k1*n2), w = e^(-+2*pi*i/N)
-    return (
-        f.real.astype(np.float32),
-        f.imag.astype(np.float32),
-        w.real.astype(np.float32),
-        w.imag.astype(np.float32),
-    )
+def dft_constants(inverse: bool):
+    """(F128_re, F128_im, T_re, T_im, G) as float32 numpy arrays.
 
-
-def apply_4step(xre, xim, fre, fim, wre, wim, *, inverse: bool):
-    """The 4-step DFT math on (b, 4096) re/im planes, VMEM-composable.
-
-    Shared by the standalone FFT kernel body and the fused decompress kernel
-    (``kernels/fused_decompress.py``), which runs it as the last stage of one
-    VMEM-resident pass.  Returns (out_re, out_im), each (b, 4096).
+    G is the (64, 64) real block form of F32 (scaled by 1/N for the
+    inverse): [[re, -im], [im, re]] maps stacked [D_re; D_im] to [O_re; O_im].
     """
-    b = xre.shape[0]  # chunks in this block
-
-    # stage 0: matrix view — (b, 4096) -> (b, 64, 64) -> (64, b*64)
-    xre = xre.reshape(b, N1, N2).transpose(1, 0, 2).reshape(N1, b * N2)
-    xim = xim.reshape(b, N1, N2).transpose(1, 0, 2).reshape(N1, b * N2)
-
-    # stage 1: A = F64 @ xm (complex x complex as 4 real matmuls)
-    dot = functools.partial(jax.lax.dot, precision=jax.lax.Precision.HIGHEST)
-    are = dot(fre, xre) - dot(fim, xim)
-    aim = dot(fre, xim) + dot(fim, xre)
-
-    # stage 2: twiddle — W broadcast over the b chunks along columns
-    a_re = are.reshape(N1, b, N2)
-    a_im = aim.reshape(N1, b, N2)
-    w_re = wre[:, None, :]
-    w_im = wim[:, None, :]
-    bre = a_re * w_re - a_im * w_im
-    bim = a_re * w_im + a_im * w_re
-
-    # stage 3: Xm = B @ F64^T, batched along rows -> (b*64, 64)
-    bre2 = bre.transpose(1, 0, 2).reshape(b * N1, N2)
-    bim2 = bim.transpose(1, 0, 2).reshape(b * N1, N2)
-    ft_re, ft_im = fre.T, fim.T
-    xmre = dot(bre2, ft_re) - dot(bim2, ft_im)
-    xmim = dot(bre2, ft_im) + dot(bim2, ft_re)
-
-    # stage 4: transpose read-out X[k2*64 + k1] = Xm[k1, k2]
-    xmre = xmre.reshape(b, N1, N2).transpose(0, 2, 1).reshape(b, CHUNK)
-    xmim = xmim.reshape(b, N1, N2).transpose(0, 2, 1).reshape(b, CHUNK)
-    scale = (1.0 / CHUNK) if inverse else 1.0
-    return xmre * scale, xmim * scale
+    sign = 2.0 if inverse else -2.0
+    j2 = np.arange(N2)
+    f128 = np.exp(sign * 1j * np.pi * np.outer(j2, j2) / N2)
+    t = np.exp(sign * 1j * np.pi * np.outer(np.arange(N1), j2) / CHUNK)
+    j1 = np.arange(N1)
+    f32 = np.exp(sign * 1j * np.pi * np.outer(j1, j1) / N1)
+    if inverse:
+        f32 = f32 / CHUNK
+    g = np.block([[f32.real, -f32.imag], [f32.imag, f32.real]])
+    return tuple(a.astype(np.float32)
+                 for a in (f128.real, f128.imag, t.real, t.imag, g))
 
 
-def _fft_body(fre_ref, fim_ref, wre_ref, wim_ref, xre_ref, xim_ref, ore_ref, oim_ref, *, inverse: bool):
-    out_re, out_im = apply_4step(
-        xre_ref[...], xim_ref[...], fre_ref[...], fim_ref[...],
-        wre_ref[...], wim_ref[...], inverse=inverse,
-    )
-    ore_ref[...] = out_re
-    oim_ref[...] = out_im
+def _dot(a, b):
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def dft_rows(s, f_re, f_im, t_re, t_im, g):
+    """One chunk's 4-step DFT: stacked [S_re; S_im] (64, 128) in the
+    ``S[k1, k2] = X[k1 + 32*k2]`` view -> stacked [O_re; O_im] (64, 128),
+    each half the transformed chunk in row-major order.
+
+    Shared by the standalone FFT kernel and the fused decompress kernel
+    (``kernels/fused_decompress.py``), which runs it as the last stage of
+    one VMEM-resident pass."""
+    sf_re = _dot(s, f_re)  # [S_re F_re; S_im F_re]
+    sf_im = _dot(s, f_im)  # [S_re F_im; S_im F_im]
+    c_re = sf_re[:N1] - sf_im[N1:]
+    c_im = sf_im[:N1] + sf_re[N1:]
+    d_re = c_re * t_re - c_im * t_im
+    d_im = c_re * t_im + c_im * t_re
+    return _dot(g, jnp.concatenate([d_re, d_im], axis=0))
+
+
+def _fft_body(f_re_ref, f_im_ref, t_re_ref, t_im_ref, g_ref, s_ref, o_ref):
+    consts = (f_re_ref[...], f_im_ref[...], t_re_ref[...], t_im_ref[...],
+              g_ref[...])
+    for i in range(s_ref.shape[0]):  # static unroll over the block's chunks
+        o_ref[i] = dft_rows(s_ref[i], *consts)
 
 
 @functools.partial(jax.jit, static_argnames=("inverse", "block_chunks", "interpret"))
@@ -123,26 +114,31 @@ def fft4096_pallas(
 ):
     """Batched 4096-pt complex FFT: (rows, 4096) re/im -> (rows, 4096) re/im.
 
-    VMEM per block at block_chunks=8: 8*4096*4B*2(re,im)*3(live stages) ≈ 1.5MB
-    — comfortably under the ~16MB/core budget, leaving room for double
-    buffering.
+    XLA builds the (32, 128) input view of each chunk; the kernel runs the
+    matmul stages.  VMEM per block at block_chunks=8: 8 chunks x 2 planes x
+    (input + output) x 32 KiB ≈ 1 MiB plus ~200 KiB of constants.
     """
     interpret = resolve_interpret(interpret)
     rows, n = x_re.shape
     assert n == CHUNK, f"kernel is specialized to {CHUNK}-pt chunks"
     block_chunks = min(block_chunks, rows)
     grid = (pl.cdiv(rows, block_chunks),)
-    fre, fim, wre, wim = (jnp.asarray(c) for c in _dft_constants(inverse))
-    const_spec = pl.BlockSpec((N1, N2), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    data_spec = pl.BlockSpec((block_chunks, CHUNK), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        functools.partial(_fft_body, inverse=inverse),
+    consts = [jnp.asarray(c) for c in dft_constants(inverse)]
+
+    def view(x):  # S[k1, k2] = x[k1 + 32*k2]
+        return x.astype(jnp.float32).reshape(rows, N2, N1).transpose(0, 2, 1)
+
+    s = jnp.concatenate([view(x_re), view(x_im)], axis=1)  # (rows, 64, 128)
+    data_spec = pl.BlockSpec((block_chunks, 2 * N1, N2), lambda i: (i, 0, 0),
+                             memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        _fft_body,
         grid=grid,
-        in_specs=[const_spec] * 4 + [data_spec] * 2,
-        out_specs=[data_spec] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, CHUNK), jnp.float32),
-            jax.ShapeDtypeStruct((rows, CHUNK), jnp.float32),
-        ],
+        in_specs=[pl.BlockSpec(c.shape, lambda i: (0, 0),
+                               memory_space=pltpu.VMEM) for c in consts]
+        + [data_spec],
+        out_specs=data_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, 2 * N1, N2), jnp.float32),
         interpret=interpret,
-    )(fre, fim, wre, wim, x_re.astype(jnp.float32), x_im.astype(jnp.float32))
+    )(*consts, s)
+    return out[:, :N1].reshape(rows, CHUNK), out[:, N1:].reshape(rows, CHUNK)

@@ -2,18 +2,36 @@
 
 The paper runs four separate GPU passes (§III-D's own cost model weights the
 elementwise pass 4x: cost = M*(4/T_m + 1/T_f + 1/T_p + 1/T_s)).  On TPU the
-spectrum tile can stay resident in VMEM through magnitude -> bisection
-threshold -> one-hot compaction -> range quantization, cutting the HBM
-round-trips of the compress stage from
+spectrum tile can stay resident in VMEM through threshold -> compaction ->
+range quantization, cutting the HBM round-trips of the compress stage from
 
     read re,im (8B/bin) + write mag (4) + read mag (4) + write tau
   + read re,im,mag (12) + write packed (..)    ~ 28 B/bin
 to
-    read re,im (8B/bin) + write codes+idx (~0.9 B/bin @ theta=0.7)
+    read re,im,mag (12 B/bin) + write codes+idx (~0.9 B/bin @ theta=0.7)
 
-a ~3.1x reduction of the compression stage's memory term (EXPERIMENTS.md
-§Perf, hypothesis H-K1).  Numerics identical to the unfused kernels
-(tests/test_kernels.py::test_fused_matches_unfused).
+(EXPERIMENTS.md §Perf, hypothesis H-K1).  The ranking magnitude is an
+input, not recomputed in-register: the kept set must be exactly the set the
+caller's threshold and quantizer range were computed over, and Mosaic's
+``sqrt`` need not round like XLA's (on a v5e the two disagreed often enough
+to move the kept set of a few rows in 1e5).  Numerics identical to the
+unfused kernels (tests/test_kernels.py::test_fused_matches_unfused).
+
+Compaction is the paper's prefix-sum pack rebuilt from lane rotations, the
+one data movement the TPU vector unit does natively (``compact_lanes``):
+
+1. running count of the kept mask: a Hillis-Steele scan, log2(W) rotate+add
+   steps over the lane axis;
+2. each kept lane must move left by ``gap`` = the dropped lanes before it.
+   Moving by the bits of ``gap`` from the lowest up — shift by 2^b where
+   bit b is set — never lands two lanes on one slot, because ``gap`` is
+   non-decreasing across kept lanes and any two kept lanes are further
+   apart than their gaps differ.  log2(W) rotate+select steps.
+
+Values move and are never summed, so the packed planes are bit-exact copies.
+Rows are padded to a whole number of 128-lane tiles inside the kernel (the
+block overhangs the array; the overhang is masked), so the rotations always
+span full tiles.
 """
 
 from __future__ import annotations
@@ -26,17 +44,63 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core import selection
-from repro.kernels.range_quant import encode_math
+from repro.core.quantizer import encode_math
 from repro.kernels.runtime import resolve_interpret
 
-__all__ = ["fused_compress_pallas"]
+__all__ = ["fused_compress_pallas", "compact_lanes", "lane_pad"]
 
-_K_TILE = 128
+_LANE = 128
+# rows per grid step; at 2049 bins the double-buffered input blocks take
+# ~1.7 MB of VMEM and the compaction's live planes a few MB more
+_BLOCK_ROWS = 32
 
 
-def _fused_body(params_ref, re_ref, im_ref, w_ref, tau_in_ref,
-                rec_ref, imc_ref, idx_ref, tau_ref, *, k_keep: int, k_pad: int,
-                m_bits: int, per_row: bool = False):
+def lane_pad(cols: int) -> int:
+    """``cols`` rounded up to whole 128-lane tiles."""
+    return -(-cols // _LANE) * _LANE
+
+
+def _prefix_count(keep, col):
+    """Inclusive running count of ``keep`` along lanes (int32)."""
+    w = keep.shape[-1]
+    cnt = keep.astype(jnp.int32)
+    s = 1
+    while s < w:
+        cnt = cnt + jnp.where(col >= s, pltpu.roll(cnt, s, 1), 0)
+        s *= 2
+    return cnt
+
+
+def compact_lanes(planes, keep):
+    """Stable left-compaction of every row's kept lanes.
+
+    ``planes`` are (r, W) arrays and ``keep`` an (r, W) bool mask, W a
+    multiple of 128.  Returns (packed planes, filled): kept values of each
+    row occupy its first ``count`` lanes in lane order, every other lane
+    holds 0, and ``filled`` marks the occupied lanes.
+    """
+    w = keep.shape[-1]
+    col = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+    gap = jnp.where(keep, col + 1 - _prefix_count(keep, col), -1)
+    planes = [jnp.where(keep, p, jnp.zeros_like(p)) for p in planes]
+    s = 1
+    while s < w:
+        gap_in = pltpu.roll(gap, w - s, 1)  # lane c sees lane c + s
+        arrive = (gap_in >= 0) & ((gap_in & s) != 0)
+        stay = (gap >= 0) & ((gap & s) == 0)
+        planes = [
+            jnp.where(arrive, pltpu.roll(p, w - s, 1),
+                      jnp.where(stay, p, jnp.zeros_like(p)))
+            for p in planes
+        ]
+        gap = jnp.where(arrive, gap_in, jnp.where(stay, gap, -1))
+        s *= 2
+    return planes, gap >= 0
+
+
+def _fused_body(params_ref, re_ref, im_ref, mag_ref, tau_in_ref,
+                rec_ref, imc_ref, idx_ref, tau_ref, *, cols: int, k_keep: int,
+                k_pad: int, m_bits: int, per_row: bool = False):
     if per_row:
         # batched-bucket mode (DESIGN.md §14): each row carries its own
         # quantizer fit — params ride a VMEM plane, one lane-tile wide
@@ -47,17 +111,13 @@ def _fused_body(params_ref, re_ref, im_ref, w_ref, tau_in_ref,
         eps = params_ref[0]
         p_codes = params_ref[1]
         n_neg = params_ref[2]
-    m_scale = float(1 << m_bits)
+    col = jax.lax.broadcasted_iota(jnp.int32, re_ref.shape, 1)
+    valid = col < cols  # lanes past the array edge hold garbage
+    re = jnp.where(valid, re_ref[...], 0.0)
+    im = jnp.where(valid, im_ref[...], 0.0)
+    mag = jnp.where(valid, mag_ref[...], -1.0)  # overhang is never kept
 
-    re = re_ref[...]
-    im = im_ref[...]
-    w = w_ref[...]  # (1, cols) hermitian weights
-    r, cols = re.shape
-
-    # 1. weighted magnitude (stays in VMEM)
-    mag = jnp.sqrt(re * re + im * im) * w
-
-    # 2. threshold: caller-provided (the engine shares ONE bisection between
+    # 1. threshold: caller-provided (the engine shares ONE bisection between
     # the quantizer range fit and this kernel), or bisected in-kernel
     # (invariant: count(>=lo) >= k > count(>=hi))
     if tau_in_ref is not None:
@@ -69,30 +129,20 @@ def _fused_body(params_ref, re_ref, im_ref, w_ref, tau_in_ref,
         tau = selection.bisect_tau(mag, k_keep)
     tau_ref[...] = tau[:, None]
 
-    # 3. compaction positions
-    mask = (mag >= tau[:, None]).astype(jnp.float32)
-    pos = jnp.cumsum(mask, axis=-1) - 1.0
-    pos = jnp.where(mask > 0, pos, -1.0)
-    col_iota = jax.lax.broadcasted_iota(jnp.float32, (r, cols), 1)
+    # 2. compaction, then 3. quantize the first k_pad slots in registers
+    # (shared quantizer math keeps codes bitwise-equal to the staged kernel);
+    # empty slots stay code 0 / index 0
+    (re_c, im_c, ix_c), filled = compact_lanes([re, im, col],
+                                               mag >= tau[:, None])
+    filled = filled[:, :k_pad]
 
-    # 4. quantize-then-pack per 128-slot tile (values quantized in registers;
-    # shared quantizer math keeps codes bitwise-equal to the staged kernel)
-    def q_encode(a_signed):
-        return encode_math(a_signed, eps, p_codes, n_neg, m_scale)
+    def codes(a):
+        q = encode_math(a[:, :k_pad], eps, p_codes, n_neg, m_bits)
+        return jnp.where(filled, q, 0).astype(rec_ref.dtype)
 
-    n_tiles = pl.cdiv(k_pad, _K_TILE)
-    for t in range(n_tiles):
-        slot = jax.lax.broadcasted_iota(jnp.float32, (1, 1, _K_TILE), 2) + t * _K_TILE
-        onehot = (pos[:, :, None] == slot).astype(jnp.float32)  # (r, cols, 128)
-        re_t = jnp.sum(re[:, :, None] * onehot, axis=1)
-        im_t = jnp.sum(im[:, :, None] * onehot, axis=1)
-        ix_t = jnp.sum(col_iota[:, :, None] * onehot, axis=1)
-        filled = jnp.sum(onehot, axis=1) > 0  # padding slots stay code 0
-        rec_ref[:, t * _K_TILE:(t + 1) * _K_TILE] = jnp.where(
-            filled, q_encode(re_t), 0.0).astype(rec_ref.dtype)
-        imc_ref[:, t * _K_TILE:(t + 1) * _K_TILE] = jnp.where(
-            filled, q_encode(im_t), 0.0).astype(imc_ref.dtype)
-        idx_ref[:, t * _K_TILE:(t + 1) * _K_TILE] = ix_t.astype(jnp.int32)
+    rec_ref[...] = codes(re_c)
+    imc_ref[...] = codes(im_c)
+    idx_ref[...] = ix_c[:, :k_pad]
 
 
 @functools.partial(jax.jit, static_argnames=("k_keep", "m_bits", "n_bits",
@@ -100,7 +150,7 @@ def _fused_body(params_ref, re_ref, im_ref, w_ref, tau_in_ref,
 def fused_compress_pallas(
     re2d: jnp.ndarray,
     im2d: jnp.ndarray,
-    weights: jnp.ndarray,  # (cols,) hermitian weights
+    mag2d: jnp.ndarray,  # (rows, cols) ranking magnitudes of the same bins
     eps: jnp.ndarray,
     p_codes: jnp.ndarray,
     tau: jnp.ndarray = None,  # optional (rows,) or (rows, 1) threshold
@@ -108,13 +158,14 @@ def fused_compress_pallas(
     k_keep: int,
     n_bits: int = 8,
     m_bits: int = 3,
-    block_rows: int = 4,
+    block_rows: int = _BLOCK_ROWS,
     interpret: bool = None,
 ):
     """(rows, cols) spectrum planes -> (re_codes u8, im_codes u8, idx i32, tau).
 
-    With ``tau=None`` the kernel bisects for the keep count ``k_keep``
-    itself; a caller that already ran the threshold kernel (the engine does,
+    Keeps, per row, the bins whose ``mag2d`` is at least tau.  With
+    ``tau=None`` the kernel bisects for the keep count ``k_keep`` itself; a
+    caller that already ran the threshold kernel (the engine does,
     to fit the quantizer range over the kept set) passes its tau in and the
     in-kernel search is skipped — one bisection per compress, and the mask
     provably matches the fit.  The payload width is padded to the 128-lane
@@ -128,14 +179,15 @@ def fused_compress_pallas(
     """
     interpret = resolve_interpret(interpret)
     rows, cols = re2d.shape
-    k = ((k_keep + _K_TILE - 1) // _K_TILE) * _K_TILE
+    lanes = lane_pad(cols)
+    k = lane_pad(k_keep)
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     n_neg = (1 << n_bits) - 1 - p_codes
     per_row = jnp.ndim(eps) == 1
     if per_row:
         # (rows, lane-tile) plane: col 0 = eps, 1 = P, 2 = n_neg, rest pad
-        params = jnp.zeros((rows, _K_TILE), jnp.float32)
+        params = jnp.zeros((rows, _LANE), jnp.float32)
         params = (params.at[:, 0].set(jnp.asarray(eps, jnp.float32))
                   .at[:, 1].set(p_codes.astype(jnp.float32))
                   .at[:, 2].set(n_neg.astype(jnp.float32)))
@@ -149,20 +201,19 @@ def fused_compress_pallas(
                                   memory_space=pltpu.VMEM)
     out_dtype = jnp.uint8 if n_bits <= 8 else jnp.uint16
     in_specs = [
-        data(_K_TILE) if per_row else pl.BlockSpec(memory_space=pltpu.SMEM),
-        data(cols), data(cols),
-        pl.BlockSpec((1, cols), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        data(_LANE) if per_row else pl.BlockSpec(memory_space=pltpu.SMEM),
+        # the planes' blocks overhang the (rows, cols) arrays to whole tiles
+        data(lanes), data(lanes), data(lanes),
     ]
-    args = [params, re2d.astype(jnp.float32), im2d.astype(jnp.float32),
-            weights.reshape(1, -1).astype(jnp.float32)]
+    args = [params] + [a.astype(jnp.float32) for a in (re2d, im2d, mag2d)]
+    statics = dict(cols=cols, k_keep=k_keep, k_pad=k, m_bits=m_bits,
+                   per_row=per_row)
     if tau is None:
-        def body(p_ref, re_ref, im_ref, w_ref, *out_refs):
-            _fused_body(p_ref, re_ref, im_ref, w_ref, None, *out_refs,
-                        k_keep=k_keep, k_pad=k, m_bits=m_bits,
-                        per_row=per_row)
+        def body(p_ref, re_ref, im_ref, mag_ref, *out_refs):
+            _fused_body(p_ref, re_ref, im_ref, mag_ref, None, *out_refs,
+                        **statics)
     else:
-        body = functools.partial(_fused_body, k_keep=k_keep, k_pad=k,
-                                 m_bits=m_bits, per_row=per_row)
+        body = functools.partial(_fused_body, **statics)
         in_specs.append(data(1))
         args.append(tau.reshape(rows, 1).astype(jnp.float32))
     return pl.pallas_call(

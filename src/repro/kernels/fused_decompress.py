@@ -19,10 +19,18 @@ scatter itself: each kept rfft coefficient (value v at bin i) contributes
     spectrum[i]        += v          (direct)
     spectrum[4096 - i] += conj(v)    (mirror, interior bins 1..2047 only)
 
-as a one-hot contraction over frequency tiles — no lane-axis flips, which
-Mosaic lowers poorly; DC (0) and Nyquist (2048) are their own mirrors and
-contribute once.  Padding slots (code 0 at index 0) decode to 0.0 and add
-nothing, so payload widths padded to the 128-lane tile are harmless.
+DC (0) and Nyquist (2048) are their own mirrors and contribute once.
+
+The scatter lands directly in the iFFT's (32, 128) input view
+``S[k1, k2] = X[k1 + 32*k2]`` (``fft4step.dft_rows``) as one matmul per
+chunk: slot j contributes ``v_j * [k1_j == row] * [k2_j == col]``, i.e.
+``S = L @ R^T`` with L = (32, k) value-weighted row selectors and
+R = (128, k) column selectors — both built by comparing the slot indices
+against an iota, and each S entry receives exactly one slot, so the matmul
+moves values without summing them.  Any slot order works (reference
+payloads are magnitude-ordered, pallas payloads index-ordered).  Padding
+slots (code 0 at index 0) decode to 0.0 and add nothing, so payload widths
+padded to the 128-lane tile are harmless.
 
 Numerics match the unfused three-stage path to f32 matmul-FFT tolerance
 (tests/test_engine.py::test_fused_decompress_matches_unfused).
@@ -37,20 +45,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.core.quantizer import decode_math
 from repro.kernels import fft4step
-from repro.kernels.range_quant import decode_math
 from repro.kernels.runtime import resolve_interpret
 
 __all__ = ["fused_decompress_pallas"]
 
 _K_TILE = 128
-_F_TILE = 512
 _CHUNK = fft4step.CHUNK
 _NYQUIST = _CHUNK // 2
+_N1, _N2 = fft4step.N1, fft4step.N2
+_K1_BITS = _N1.bit_length() - 1  # bin >> _K1_BITS == bin // 32
+_BLOCK_ROWS = 8
+
+
+def _dot_nt(a, b):
+    """a (m, k) . b (n, k)^T -> (m, n), exact f32 for one-hot ``b``."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _scatter_view(re_k, im_k, idx):
+    """One chunk's kept coefficients (1, k) -> Hermitian-completed spectrum
+    in the iFFT input view, stacked [S_re; S_im] (64, 128)."""
+    interior = (idx >= 1) & (idx <= _NYQUIST - 1)
+    mirror = jnp.where(interior, _CHUNK - idx, -1)  # -1: no mirror slot
+    k = idx.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (_N1, k), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (_N2, k), 0)
+
+    def part(bins, im_sign):
+        # bin = k1 + 32*k2; a bin of -1 selects no column (-1 >> 5 == -1)
+        on_row = (bins & (_N1 - 1)) == row
+        on_col = ((bins >> _K1_BITS) == col).astype(jnp.float32)
+        lhs = jnp.concatenate([jnp.where(on_row, re_k, 0.0),
+                               jnp.where(on_row, im_sign * im_k, 0.0)], axis=0)
+        return _dot_nt(lhs, on_col)
+
+    return part(idx, 1.0) + part(mirror, -1.0)
 
 
 def _fused_decompress_body(params_ref, rec_ref, imc_ref, idx_ref,
-                           fre_ref, fim_ref, wre_ref, wim_ref,
+                           f_re_ref, f_im_ref, t_re_ref, t_im_ref, g_ref,
                            out_ref, *, m_bits: int, per_row: bool = False):
     if per_row:
         # batched-bucket mode: one quantizer fit per row (DESIGN.md §14)
@@ -59,38 +97,19 @@ def _fused_decompress_body(params_ref, rec_ref, imc_ref, idx_ref,
     else:
         eps = params_ref[0]
         p_codes = params_ref[1]
-    m_scale = float(1 << m_bits)
+    # 1. dequantize both code planes for the whole block (shared quantizer
+    # math; Mosaic widens u8 codes only to integers, not to floats)
+    re_k = decode_math(rec_ref[...].astype(jnp.int32), eps, p_codes, m_bits)
+    im_k = decode_math(imc_ref[...].astype(jnp.int32), eps, p_codes, m_bits)
+    idx = idx_ref[...]
+    consts = (f_re_ref[...], f_im_ref[...], t_re_ref[...], t_im_ref[...],
+              g_ref[...])
 
-    # 1. dequantize both code planes (stays in VMEM; shared quantizer math)
-    re_k = decode_math(rec_ref[...].astype(jnp.float32), eps, p_codes, m_scale)
-    im_k = decode_math(imc_ref[...].astype(jnp.float32), eps, p_codes, m_scale)
-    idx = idx_ref[...].astype(jnp.float32)  # bins <= 2048: exact in f32
-    r, k = re_k.shape
-
-    # 2. Hermitian scatter: direct bin + conjugate mirror, tiled one-hot
-    # contraction over the 4096 output bins.  Interior bins (1..2047) mirror
-    # to 4096-i; DC/Nyquist map to themselves and must not double-count.
-    interior = (idx >= 1.0) & (idx <= float(_NYQUIST - 1))
-    mirror_idx = jnp.where(interior, float(_CHUNK) - idx, -1.0)  # -1: no slot
-
-    full_re_tiles = []
-    full_im_tiles = []
-    n_tiles = pl.cdiv(_CHUNK, _F_TILE)
-    for t in range(n_tiles):  # static unroll
-        col = jax.lax.broadcasted_iota(jnp.float32, (1, 1, _F_TILE), 2) + t * _F_TILE
-        direct = (idx[:, :, None] == col).astype(jnp.float32)  # (r, k, F_TILE)
-        mirror = (mirror_idx[:, :, None] == col).astype(jnp.float32)
-        full_re_tiles.append(jnp.sum(re_k[:, :, None] * (direct + mirror), axis=1))
-        full_im_tiles.append(jnp.sum(im_k[:, :, None] * (direct - mirror), axis=1))
-    full_re = jnp.concatenate(full_re_tiles, axis=-1)  # (r, 4096)
-    full_im = jnp.concatenate(full_im_tiles, axis=-1)
-
-    # 3. inverse 4-step FFT on the MXU; hermitian input -> real output
-    out_re, _ = fft4step.apply_4step(
-        full_re, full_im, fre_ref[...], fim_ref[...], wre_ref[...], wim_ref[...],
-        inverse=True,
-    )
-    out_ref[...] = out_re
+    # 2. per chunk: Hermitian scatter into the iFFT view, then 3. the
+    # inverse 4-step FFT on the MXU; hermitian input -> real output
+    for i in range(out_ref.shape[0]):  # static unroll over the block's rows
+        s = _scatter_view(re_k[i:i + 1], im_k[i:i + 1], idx[i:i + 1])
+        out_ref[i] = fft4step.dft_rows(s, *consts)[:_N1]
 
 
 @functools.partial(jax.jit, static_argnames=("m_bits", "block_rows", "interpret"))
@@ -102,7 +121,7 @@ def fused_decompress_pallas(
     p_codes: jnp.ndarray,
     *,
     m_bits: int = 3,
-    block_rows: int = 4,
+    block_rows: int = _BLOCK_ROWS,
     interpret: bool = None,
 ) -> jnp.ndarray:
     """Quantized payload planes -> (rows, 4096) f32 time-domain chunks.
@@ -134,20 +153,21 @@ def fused_decompress_pallas(
             jnp.asarray(eps, jnp.float32),
             p_codes.astype(jnp.float32),
         ])
-    fre, fim, wre, wim = (jnp.asarray(c)
-                          for c in fft4step._dft_constants(inverse=True))
-    const_spec = pl.BlockSpec((fft4step.N1, fft4step.N2), lambda i: (0, 0),
-                              memory_space=pltpu.VMEM)
+    consts = [jnp.asarray(c) for c in fft4step.dft_constants(inverse=True)]
     data = lambda c: pl.BlockSpec((block_rows, c), lambda i: (i, 0),
                                   memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_decompress_body, m_bits=m_bits,
                           per_row=per_row),
         grid=grid,
         in_specs=[data(_K_TILE) if per_row
                   else pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [data(k_pad)] * 3 + [const_spec] * 4,
-        out_specs=data(_CHUNK),
-        out_shape=jax.ShapeDtypeStruct((rows, _CHUNK), jnp.float32),
+        + [data(k_pad)] * 3
+        + [pl.BlockSpec(c.shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
+           for c in consts],
+        out_specs=pl.BlockSpec((block_rows, _N1, _N2), lambda i: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((rows, _N1, _N2), jnp.float32),
         interpret=interpret,
-    )(params, re_codes, im_codes, idx.astype(jnp.int32), fre, fim, wre, wim)
+    )(params, re_codes, im_codes, idx.astype(jnp.int32), *consts)
+    return out.reshape(rows, _CHUNK)

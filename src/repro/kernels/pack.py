@@ -2,17 +2,14 @@
 
 The paper's GPU pack is: status bitmap -> parallel prefix sum -> scattered
 write (689x speedup over 1 thread on V100).  TPUs have no efficient in-VMEM
-scatter, so the adaptation (DESIGN.md §2) reformulates compaction as
-**cumsum + one-hot contraction**, both native TPU operations:
+scatter, so the adaptation (DESIGN.md §2) builds the same compaction from
+lane rotations (``fused_compress.compact_lanes``: a log-step prefix count,
+then log-step shifts of each kept lane by the bits of its gap), which move
+values without arithmetic on them.
 
-    pos[i]   = cumsum(mask)[i] - 1                (position among kept)
-    vals[j]  = sum_i x[i]   * mask[i] * [pos[i] == j]
-    idx[j]   = sum_i i      * mask[i] * [pos[i] == j]
-
-The contraction is tiled over the k output slots (tile 128 = lane width) so
-the one-hot never materializes beyond a ``(rows, cols, 128)`` VMEM slab.
-Unpack is the transpose: ``dense[i] = sum_j vals[j] * [idx[j] == i]`` tiled
-over the dense axis.  Round-trips exactly against the jnp oracle.
+Unpack is the transpose: ``dense[i] = sum_j vals[j] * [idx[j] == i]``, one
+one-hot matmul per row and 512-column tile (each output receives at most
+one slot).  Round-trips exactly against the jnp oracle.
 """
 
 from __future__ import annotations
@@ -24,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.fused_compress import compact_lanes, lane_pad
 from repro.kernels.runtime import resolve_interpret
 
 __all__ = ["pack_pallas", "unpack_pallas"]
@@ -32,23 +30,14 @@ _K_TILE = 128
 _F_TILE = 512
 
 
-def _pack_body(x_ref, tau_ref, vals_ref, idx_ref, *, k: int):
-    x = x_ref[...]  # (r, cols)
-    tau = tau_ref[...]  # (r, 1)
-    r, cols = x.shape
-    mask = (jnp.abs(x) >= tau).astype(jnp.float32)
-    pos = jnp.cumsum(mask, axis=-1) - 1.0  # (r, cols) position among kept
-    pos = jnp.where(mask > 0, pos, -1.0)  # dropped -> sentinel
-    col_iota = jax.lax.broadcasted_iota(jnp.float32, (r, cols), 1)
-
-    n_tiles = pl.cdiv(k, _K_TILE)
-    for t in range(n_tiles):  # static unroll: k is static
-        slot = jax.lax.broadcasted_iota(jnp.float32, (1, 1, _K_TILE), 2) + t * _K_TILE
-        onehot = (pos[:, :, None] == slot).astype(jnp.float32)  # (r, cols, K_TILE)
-        vals_t = jnp.sum(x[:, :, None] * onehot, axis=1)  # (r, K_TILE)
-        idx_t = jnp.sum(col_iota[:, :, None] * onehot, axis=1)
-        vals_ref[:, t * _K_TILE : (t + 1) * _K_TILE] = vals_t
-        idx_ref[:, t * _K_TILE : (t + 1) * _K_TILE] = idx_t.astype(jnp.int32)
+def _pack_body(x_ref, tau_ref, vals_ref, idx_ref, *, cols: int, k: int):
+    col = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 1)
+    valid = col < cols  # lanes past the array edge hold garbage
+    x = jnp.where(valid, x_ref[...], 0.0)
+    keep = valid & (jnp.abs(x) >= tau_ref[...])
+    (vals, idx), _ = compact_lanes([x, col], keep)
+    vals_ref[...] = vals[:, :k]
+    idx_ref[...] = idx[:, :k]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
@@ -57,7 +46,7 @@ def pack_pallas(
     tau: jnp.ndarray,
     *,
     k: int,
-    block_rows: int = 4,
+    block_rows: int = 8,
     interpret: bool = None,
 ):
     """Compact per-row elements with |x| >= tau into (vals, idx) of width k.
@@ -68,13 +57,16 @@ def pack_pallas(
     interpret = resolve_interpret(interpret)
     rows, cols = x2d.shape
     assert k % _K_TILE == 0, "pad k to a multiple of 128 (see ops.pad_k)"
+    assert k <= lane_pad(cols), "k exceeds the row width"
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     return pl.pallas_call(
-        functools.partial(_pack_body, k=k),
+        functools.partial(_pack_body, cols=cols, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0), memory_space=pltpu.VMEM),
+            # overhangs the (rows, cols) array to whole lane tiles
+            pl.BlockSpec((block_rows, lane_pad(cols)), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
             pl.BlockSpec((block_rows, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -89,17 +81,20 @@ def pack_pallas(
     )(x2d.astype(jnp.float32), tau.astype(jnp.float32))
 
 
-def _unpack_body(vals_ref, idx_ref, dense_ref, *, cols: int):
+def _unpack_body(vals_ref, idx_ref, dense_ref):
     vals = vals_ref[...]  # (r, k)
-    idx = idx_ref[...].astype(jnp.float32)  # (r, k)
+    idx = idx_ref[...]
     r, k = vals.shape
     # slots with vals == 0 are padding; idx 0 collisions are harmless (add 0)
-    n_tiles = pl.cdiv(cols, _F_TILE)
-    for t in range(n_tiles):
-        col = jax.lax.broadcasted_iota(jnp.float32, (1, 1, _F_TILE), 2) + t * _F_TILE
-        onehot = (idx[:, :, None] == col).astype(jnp.float32)  # (r, k, F_TILE)
-        dense_t = jnp.sum(vals[:, :, None] * onehot, axis=1)  # (r, F_TILE)
-        dense_ref[:, t * _F_TILE : (t + 1) * _F_TILE] = dense_t
+    for t in range(dense_ref.shape[-1] // _F_TILE):
+        col = jax.lax.broadcasted_iota(jnp.int32, (_F_TILE, k), 0) + t * _F_TILE
+        for i in range(r):  # static unroll: the one-hot differs per row
+            onehot = (idx[i:i + 1] == col).astype(jnp.float32)  # (F_TILE, k)
+            dense_ref[i:i + 1, t * _F_TILE:(t + 1) * _F_TILE] = (
+                jax.lax.dot_general(
+                    vals[i:i + 1], onehot, (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("cols", "block_rows", "interpret"))
@@ -108,7 +103,7 @@ def unpack_pallas(
     idx: jnp.ndarray,
     *,
     cols: int,
-    block_rows: int = 4,
+    block_rows: int = 8,
     interpret: bool = None,
 ):
     """Scatter (vals, idx) of width k back to a dense (rows, cols) array."""
@@ -118,7 +113,7 @@ def unpack_pallas(
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     return pl.pallas_call(
-        functools.partial(_unpack_body, cols=cols),
+        _unpack_body,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, k), lambda i: (i, 0), memory_space=pltpu.VMEM),

@@ -10,8 +10,9 @@ Codes are emitted as uint8 (n_bits <= 8) — the memory-bandwidth win (4 bytes -
 1 byte) is the entire point of the pass; see EXPERIMENTS.md §Perf for the
 fused variant that removes this pass's HBM round-trip altogether.
 
-Matches :mod:`repro.core.quantizer` bit-for-bit (tests/test_kernels.py sweeps
-shapes x dtypes against the oracle).
+Matches :mod:`repro.core.quantizer` bit-for-bit: the kernel bodies run its
+``encode_math``/``decode_math`` (tests/test_kernels.py sweeps shapes x dtypes
+against the oracle).
 """
 
 from __future__ import annotations
@@ -23,58 +24,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.core.quantizer import decode_math, encode_math
 from repro.kernels.runtime import resolve_interpret
 
-__all__ = ["encode_pallas", "decode_pallas", "encode_math", "decode_math"]
+__all__ = ["encode_pallas", "decode_pallas"]
 
 _LANE = 128  # TPU lane tile; width of the per-row params plane
-
-
-def encode_math(x, eps, p_codes, n_neg, m_scale):
-    """Range-quant ENCODE on an f32 plane (paper Alg. 1) — pure jnp math.
-
-    Shared by this kernel's body and the fused compress kernel
-    (``fused_compress.py``); one definition keeps the in-register and staged
-    quantizers bitwise-identical by construction.  Parameters ride as traced
-    f32 scalars (SMEM in the kernels).
-    """
-    a = jnp.abs(x)
-    pos = x >= 0
-
-    safe_a = jnp.maximum(a, eps)
-    q = jnp.floor(jnp.log2(safe_a) - jnp.log2(eps) + 1e-6)
-    seg_base = eps * jnp.exp2(q)
-    r = jnp.round((safe_a / seg_base - 1.0) * m_scale)
-    carry = r >= m_scale
-    q = jnp.where(carry, q + 1.0, q)
-    r = jnp.where(carry, 0.0, r)
-    idx = q * m_scale + r
-    # below-eps: nearest of {0, eps}
-    idx = jnp.where(a < eps, jnp.where(a * 2.0 >= eps, 0.0, -1.0), idx)
-    idx_pos = jnp.clip(idx, -1.0, p_codes - 1.0)
-    idx_neg = jnp.clip(idx, -1.0, jnp.maximum(n_neg, 1.0) - 1.0)
-
-    return jnp.where(
-        pos,
-        jnp.where(idx_pos < 0, 0.0, idx_pos + 1.0),
-        jnp.where(idx_neg < 0, 0.0, p_codes + idx_neg + 1.0),
-    )
-
-
-def decode_math(c, eps, p_codes, m_scale):
-    """Range-quant DECODE on an f32-carried code plane — pure jnp math.
-
-    Shared by this kernel's body and the fused decompress kernel
-    (``fused_decompress.py``)."""
-    is_zero = c == 0.0
-    is_pos = (c >= 1.0) & (c <= p_codes)
-    idx = jnp.where(is_pos, c - 1.0, c - p_codes - 1.0)
-    idx = jnp.maximum(idx, 0.0)
-    q = jnp.floor(idx / m_scale)
-    r = idx - q * m_scale
-    mag = eps * jnp.exp2(q) * (1.0 + r / m_scale)
-    val = jnp.where(is_pos, mag, -mag)
-    return jnp.where(is_zero, 0.0, val)
 
 
 def _unpack_params(params_ref, per_row: bool):
@@ -93,15 +48,14 @@ def _unpack_params(params_ref, per_row: bool):
 def _encode_body(params_ref, x_ref, codes_ref, *, m_bits: int,
                  per_row: bool = False):
     eps, p_codes, n_neg = _unpack_params(params_ref, per_row)
-    code = encode_math(x_ref[...], eps, p_codes, n_neg, float(1 << m_bits))
+    code = encode_math(x_ref[...], eps, p_codes, n_neg, m_bits)
     codes_ref[...] = code.astype(codes_ref.dtype)
 
 
 def _decode_body(params_ref, codes_ref, x_ref, *, m_bits: int,
                  per_row: bool = False):
     eps, p_codes, _ = _unpack_params(params_ref, per_row)
-    val = decode_math(codes_ref[...].astype(jnp.float32), eps, p_codes,
-                      float(1 << m_bits))
+    val = decode_math(codes_ref[...].astype(jnp.int32), eps, p_codes, m_bits)
     x_ref[...] = val.astype(x_ref.dtype)
 
 

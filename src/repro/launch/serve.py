@@ -16,10 +16,10 @@ from __future__ import annotations
 import argparse
 
 import jax
-
-from repro import jaxcompat as compat
 import jax.numpy as jnp
 
+from repro import jaxcompat as compat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import registry
 from repro.serve import Engine, ReplicaSubscriber, ServeConfig
@@ -68,6 +68,7 @@ def main(argv=None):
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.follow is not None:
         cfg, model, params = _follow_ring(args)

@@ -9,19 +9,29 @@ Examples (CPU-scale):
 On a real fleet the same entrypoint runs under the production mesh
 (--mesh production[:multi_pod]); on CPU it builds a mesh over however many
 host devices exist.
+
+``main`` is ``parse_args`` -> ``prepare`` (an ``ArchConfig`` plus the parsed
+options -> a ``TrainJob``) -> ``run``; a caller that builds its own
+configuration (``chip_smoke.py`` cuts a registry model to one chip) enters
+at ``prepare``.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any, Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import jaxcompat as compat
 
 from repro.comms.reducers import ReducerConfig
+from repro.configs.base import ArchConfig
 from repro.core import schedules as theta_schedules
 from repro.data import SyntheticConfig, SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (
     TWO_LEVEL_AXES,
     make_local_mesh,
@@ -34,7 +44,21 @@ from repro.train import TrainLoopConfig, init_state, train_loop
 from repro.train.step import StepConfig
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainJob:
+    """Everything ``train_loop`` needs, built by :func:`prepare`."""
+
+    model: Any
+    mesh: Any
+    opt_cfg: OptConfig
+    step_cfg: StepConfig
+    stream: SyntheticStream
+    state: dict
+    loop_cfg: TrainLoopConfig
+    publisher: Optional[Any] = None
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2_2b", choices=registry.ARCH_NAMES)
     ap.add_argument("--reduced", action="store_true", help="smoke-size config")
@@ -123,15 +147,17 @@ def main(argv=None):
                          "transports become available")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.nodes is not None and args.mesh != "local":
+        ap.error("--nodes builds a two-level LOCAL mesh; drop --mesh")
+    return args
 
-    cfg = registry.get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+
+def prepare(cfg: ArchConfig, args: argparse.Namespace) -> TrainJob:
+    """Model, mesh, exchange, data, state and loop for ``cfg`` under the
+    parsed options (``--arch``/``--reduced`` are the caller's business)."""
     model = registry.build(cfg)
 
     if args.nodes is not None:
-        if args.mesh != "local":
-            ap.error("--nodes builds a two-level LOCAL mesh; drop --mesh")
         mesh = make_two_level_mesh(args.nodes)
     elif args.mesh == "local":
         mesh = make_local_mesh()
@@ -196,9 +222,16 @@ def main(argv=None):
             w *= dict(mesh.shape)[ax]
         n = state["residual"].shape[0]
         state["residual"] = jnp.zeros((w, n), jnp.float32)
+    if args.mode == "compressed_dp":
+        # place the state as the step returns it (replicated; the EF residual
+        # one row per worker), so that the second step reuses the first
+        # step's executable instead of compiling again for new shardings
+        shard = lambda spec: NamedSharding(mesh, spec)
+        state = {k: jax.device_put(v, shard(
+                     P(step_cfg.manual_axes) if k == "residual" else P()))
+                 for k, v in state.items()}
 
     if args.calibrate and args.mode != "pjit":
-        import dataclasses
         import tempfile
 
         from repro.comms import calibrate as cal
@@ -249,18 +282,34 @@ def main(argv=None):
         lr_schedule=lr_schedules.warmup_cosine(max(2, args.steps // 10), args.steps),
         publish_hook=publisher.hook() if publisher is not None else None,
     )
+    return TrainJob(model, mesh, opt_cfg, step_cfg, stream, state, loop_cfg,
+                    publisher)
+
+
+def run(job: TrainJob) -> dict:
+    """Train; prints one row per logged step and returns ``train_loop``'s
+    result (state, history, health)."""
     try:
-        with compat.set_mesh(mesh):
-            result = train_loop(model, opt_cfg, step_cfg, mesh, state, stream,
-                                loop_cfg)
+        with compat.set_mesh(job.mesh):
+            result = train_loop(job.model, job.opt_cfg, job.step_cfg, job.mesh,
+                                job.state, job.stream, job.loop_cfg)
     finally:
-        if publisher is not None:
-            publisher.close()
-            print(f"[publish] closed ring at v{publisher.version} "
-                  f"({publisher.delta_bytes_total} delta bytes)")
+        if job.publisher is not None:
+            job.publisher.close()
+            print(f"[publish] closed ring at v{job.publisher.version} "
+                  f"({job.publisher.delta_bytes_total} delta bytes)")
     for row in result["history"]:
         print({k: (round(v, 4) if isinstance(v, float) else v) for k, v in row.items()})
     return result
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = registry.get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    return run(prepare(cfg, args))
 
 
 if __name__ == "__main__":

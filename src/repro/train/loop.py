@@ -6,11 +6,16 @@ Fault-tolerance contract (DESIGN.md §19):
   ``comms.faults.FaultPlan``; host-side events (``step_crash``,
   ``slow_worker``) fire here, in-step events (``nan_grad``,
   ``payload_corrupt``) ride the reducer config into the jitted step;
-* step-level recovery — a failing step (any ``_RECOVERABLE`` error) rolls
-  back to the last checkpoint and retries; with no checkpoint yet it
-  retries in place (nothing was committed), and the original error — not a
+* step-level recovery — a step failing with a host-side fault (any
+  ``_RECOVERABLE`` error: an injected crash, a float trap) rolls back to the
+  last checkpoint and retries; with no checkpoint yet it retries in place
+  (nothing was committed), and the original error — not a
   ``FileNotFoundError`` from a hopeless restore — surfaces if recovery
   fails;
+* no fallback for the device — an error from the JAX runtime (out of device
+  memory, a kernel or program the compiler refuses) propagates at once: it
+  is deterministic, so a retry cannot cure it, and walking the ladder would
+  turn a broken run into one that looks healthy;
 * degradation ladder — when retries are exhausted, or the non-finite guard
   keeps skipping steps, the loop walks ``reducers.degrade_config`` one
   rung at a time (pallas→reference, streamed→stacked, exotic transports→
@@ -28,6 +33,8 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Set
 
+from jax.errors import JaxRuntimeError
+
 from repro.comms import faults as faults_mod
 from repro.comms import reducers
 from repro.core.schedules import quantize_theta
@@ -37,28 +44,10 @@ from repro.train.step import StepConfig, build_train_step
 __all__ = ["TrainLoopConfig", "train_loop", "_RECOVERABLE"]
 
 
-def _recoverable_types():
-    """Errors the rollback/ladder path may absorb: host-side RuntimeErrors,
-    float traps, and whatever runtime-error types this jax generation
-    raises from a failing executable (modern jax subclasses RuntimeError,
-    older jaxlib spellings are added defensively)."""
-    types = [RuntimeError, FloatingPointError]
-    try:
-        from jax.errors import JaxRuntimeError
-
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jax._src.lib import xla_client
-
-        types.append(xla_client.XlaRuntimeError)
-    except (ImportError, AttributeError):
-        pass
-    return tuple(types)
-
-
-_RECOVERABLE = _recoverable_types()
+# Host-side faults the rollback/ladder path may absorb (injected crashes are
+# RuntimeErrors).  JaxRuntimeError subclasses RuntimeError and is re-raised
+# before this clause is reached: see the module docstring.
+_RECOVERABLE = (RuntimeError, FloatingPointError)
 
 
 @dataclasses.dataclass
@@ -211,6 +200,8 @@ def train_loop(
             if consecutive_skips > loop_cfg.max_retries:
                 if degrade(step, f"{consecutive_skips} consecutive skipped steps"):
                     consecutive_skips = 0
+        except JaxRuntimeError:
+            raise  # out of memory, compile failure: no retry, no ladder
         except _RECOVERABLE as e:
             retries += 1
             if retries > loop_cfg.max_retries:

@@ -78,6 +78,25 @@ def test_backend_parity_codes_bitwise(theta, n_bits, quantize):
         np.array(pal.decompress(p_ref)), x_ref, atol=5e-5)
 
 
+def test_backend_parity_codes_bitwise_under_exact_ties():
+    """Exact magnitude ties at the k-th largest bin (here by construction;
+    in f32 gradients a few rows in 1e5 have them): the pallas backend keeps
+    the bins top_k keeps — all strictly larger ones plus the lowest-index
+    tied ones — not the lowest-index k of every bin at or above tau."""
+    chunk = np.zeros(4096, np.float32)
+    chunk[0], chunk[1024], chunk[3072], chunk[2048] = 1.0, 0.25, 0.25, 0.125
+    g = jnp.asarray(np.tile(chunk, 3)) * 0.05
+    ref = FFTCompressor(_cfg("reference", theta=0.7))
+    pal = FFTCompressor(_cfg("pallas", theta=0.7))
+    p_ref, p_pal = jax.jit(ref.compress)(g), jax.jit(pal.compress)(g)
+    mag = np.abs(np.fft.rfft(np.asarray(g[:4096])))
+    assert np.sum(mag == np.sort(mag)[-p_ref.re.shape[-1]]) > 1  # ties
+    assert float(p_ref.quant.eps) == float(p_pal.quant.eps)
+    for a, b, what in zip(_sorted_planes(p_ref), _sorted_planes(p_pal),
+                          ("re", "im", "idx")):
+        np.testing.assert_array_equal(a, b, err_msg=f"{what} codes diverge")
+
+
 def test_backend_spectra_bitwise_identical():
     """The exchange path (decompress_spectrum) is shared: payloads from
     either backend produce the SAME dense spectrum bit-for-bit — this is why
@@ -143,6 +162,20 @@ def test_auto_backend_selects_reference_off_tpu():
     for a, b in ((p_auto.re, p_ref.re), (p_auto.im, p_ref.im),
                  (p_auto.idx, p_ref.idx)):
         np.testing.assert_array_equal(np.array(a), np.array(b))
+
+
+def test_auto_backend_selects_pallas_where_mosaic_compiles(monkeypatch):
+    """Where Mosaic compiles (a TPU), auto runs the fused kernels for every
+    kernel-eligible config, compress and decompress alike; only an
+    ineligible config (no fused kernel for it) goes to the reference path."""
+    monkeypatch.setattr(engine, "mosaic_available", lambda: True)
+    auto = engine.get_backend("auto")
+    assert auto._pick(_cfg("auto", theta=0.7)) is auto._pallas
+    assert auto._pick(_cfg("auto", chunk=1024)) is auto._reference
+    payload = FFTCompressor(_cfg("pallas")).compress(G)
+    np.testing.assert_array_equal(
+        np.array(auto.decompress(payload)),
+        np.array(engine.get_backend("pallas").decompress(payload)))
 
 
 def test_spec_backend_names_mirror_engine_registry():

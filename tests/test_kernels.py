@@ -114,11 +114,11 @@ def test_fused_golden_at_tile_boundary_keep_counts(k_keep):
     im = jax.random.normal(jax.random.fold_in(key, 1), (3, cols)) * 0.05
     w = cfft.hermitian_weights(1024)
 
-    rec_f, imc_f, idx_f, tau_f = fused_compress.fused_compress_pallas(
-        re, im, w, q.eps, q.p_codes, k_keep=k_keep, interpret=True)
-
     # oracle: exact k-th order statistic threshold, then index-ordered pack
     mag = jnp.sqrt(re * re + im * im) * w[None, :]
+    rec_f, imc_f, idx_f, tau_f = fused_compress.fused_compress_pallas(
+        re, im, mag, q.eps, q.p_codes, k_keep=k_keep, interpret=True)
+
     tau_r, _ = ref.threshold_ref(mag, k_keep)
     k_pad = ops.pad_k(k_keep)
     mvals, idx_r = ref.pack_ref(mag, tau_r, k_pad)
@@ -151,10 +151,10 @@ def test_fused_matches_unfused():
     re, im = ops.rfft4096(x)
     w = cfft.hermitian_weights(4096)
 
-    rec_f, imc_f, idx_f, tau_f = fused_compress.fused_compress_pallas(
-        re, im, w, q.eps, q.p_codes, k_keep=615, interpret=True)
-
     mag = jnp.sqrt(re * re + im * im) * w
+    rec_f, imc_f, idx_f, tau_f = fused_compress.fused_compress_pallas(
+        re, im, mag, q.eps, q.p_codes, k_keep=615, interpret=True)
+
     tau_u, _ = ops.threshold_select(mag, 615)
     mvals, idx_u = ops.pack_threshold(mag, tau_u, 615)
     re_k = jnp.take_along_axis(re, idx_u, axis=-1) * (mvals != 0)

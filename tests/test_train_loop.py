@@ -5,6 +5,8 @@ import os
 
 import jax
 import numpy as np
+import pytest
+from jax.errors import JaxRuntimeError
 
 from repro import jaxcompat as compat
 from repro.comms.faults import FaultPlan, StepCrash
@@ -122,3 +124,41 @@ def test_checkpoint_gc_keeps_last_k(tmp_path):
         mgr.maybe_save(s, state)
     kept = sorted(os.listdir(str(tmp_path / "gc")))
     assert kept == ["step_00000004", "step_00000005"]
+
+
+@pytest.mark.parametrize("error", [
+    JaxRuntimeError("RESOURCE_EXHAUSTED: Out of memory while trying to "
+                    "allocate 17179869184 bytes."),
+    JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                    "unsupported shape cast"),
+    ValueError("The Pallas TPU lowering currently requires that the last "
+               "two dimensions of your block shape are divisible by 8 and 128"),
+], ids=["out_of_memory", "compile_runtime", "compile_lowering"])
+def test_device_errors_propagate_without_retry_or_degradation(
+        monkeypatch, error):
+    """Out-of-memory and compile failures are deterministic: the step error
+    surfaces on its first occurrence, with no retry and no ladder rung, even
+    for a compressed exchange that has rungs left to walk."""
+    from repro.comms.reducers import ReducerConfig
+    from repro.train import loop as loop_mod
+
+    calls = []
+
+    def failing_build(*args, **kwargs):
+        def step(state, batch):
+            calls.append(1)
+            raise error
+        return step
+
+    monkeypatch.setattr(loop_mod, "build_train_step", failing_build)
+    model = LM(TINY)
+    opt = OptConfig(kind="adamw", lr=1e-3)
+    step_cfg = StepConfig(mode="compressed_dp", reducer=ReducerConfig(
+        kind="fft", axis="data", backend="pallas"))
+    mesh = make_local_mesh()
+    with compat.set_mesh(mesh), pytest.raises(type(error)) as got:
+        train_loop(model, opt, step_cfg, mesh,
+                   init_state(jax.random.PRNGKey(5), model, opt), _stream(),
+                   TrainLoopConfig(total_steps=4, log_every=100))
+    assert got.value is error
+    assert len(calls) == 1  # never retried, never rebuilt on a lower rung

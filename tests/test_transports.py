@@ -449,3 +449,19 @@ else:
 print("MESH_VALIDATION_OK")
 """)
     assert "MESH_VALIDATION_OK" in out
+
+
+def test_local_mesh_places_every_device():
+    """The default data mesh spans every device, each once — four chips
+    train as four replicas, not one."""
+    out = run_with_devices("""
+import jax
+from repro.launch.mesh import make_local_mesh
+
+mesh = make_local_mesh()
+assert dict(mesh.shape) == {"data": 4}, dict(mesh.shape)
+placed = list(mesh.devices.flat)
+assert sorted(d.id for d in placed) == sorted(d.id for d in jax.devices())
+print("LOCAL_MESH_OK")
+""", devices=4)
+    assert "LOCAL_MESH_OK" in out
