@@ -169,7 +169,8 @@ def _compress_stacked(flat: jnp.ndarray, layout, comp, monitor=None):
 
 def _irfft_rows(mean_spectrum: jnp.ndarray, chunk: int) -> jnp.ndarray:
     """(B, max_chunks, f) mean spectrum -> (B, padded_size) time domain."""
-    x = cfft.irfft_rows(mean_spectrum, chunk)
+    with jax.named_scope("exchange.irfft"):
+        x = cfft.irfft_rows(mean_spectrum, chunk)
     return x.reshape(mean_spectrum.shape[0], -1)
 
 
@@ -200,7 +201,8 @@ def _mean_spectrum(gathered, comp) -> jnp.ndarray:
     acc = comp.decompress_spectrum(worker(0))
     for w in range(1, p):
         acc = comp.decompress_spectrum(worker(w), into=acc)
-    return acc * (1.0 / p)
+    with jax.named_scope("exchange.fold"):
+        return acc * (1.0 / p)
 
 
 def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
@@ -212,7 +214,8 @@ def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     gathered = jax.lax.all_gather(payload, axis)  # leading axis: workers
     if hasattr(comp, "decompress_spectrum"):
         mean_spectrum = _mean_spectrum(gathered, comp)
-        return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
+        with jax.named_scope("exchange.irfft"):
+            return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
     decompressed = jax.vmap(comp.decompress)(gathered)
     return _ordered_worker_mean(decompressed)
 
@@ -233,7 +236,8 @@ def _psum_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
         # backend, and two f32 reductions lower to one fused collective anyway
         summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), axis)
         mean_spectrum = (summed[0] + 1j * summed[1]) * inv_p
-        return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
+        with jax.named_scope("exchange.irfft"):
+            return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
     return jax.lax.psum(comp.decompress(payload), axis) * inv_p
 
 
@@ -522,7 +526,8 @@ class HierarchicalTransport(Transport):
         rows = bucketing.stack_buckets(flat, layout)  # (B, padded)
         if hasattr(comp, "decompress_spectrum"):
             x3 = rows.reshape(layout.n_buckets, -1, layout.chunk)
-            spec = cfft.rfft_rows(x3)  # DENSE spectra — no top-k
+            with jax.named_scope("exchange.rfft"):
+                spec = cfft.rfft_rows(x3)  # DENSE spectra — no top-k
             summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), local_ax)
             node_mean = bucketing.unstack_buckets(
                 _irfft_rows((summed[0] + 1j * summed[1]) * inv_l, layout.chunk),

@@ -297,16 +297,18 @@ class CompressorBackend:
         the stacked (n_buckets, max_chunks, k) payload, and any worker-vmap
         of either (see ``_scatter_spectrum``).
         """
-        re, im = payload.re, payload.im
-        if payload.quant is not None:
-            re, im = q_decode(re, payload.quant), q_decode(im, payload.quant)
-        return _scatter_spectrum(payload.idx, re.astype(jnp.float32),
-                                 im.astype(jnp.float32),
-                                 payload.chunk // 2 + 1, into)
+        with jax.named_scope("exchange.fold"):
+            re, im = payload.re, payload.im
+            if payload.quant is not None:
+                re, im = q_decode(re, payload.quant), q_decode(im, payload.quant)
+            return _scatter_spectrum(payload.idx, re.astype(jnp.float32),
+                                     im.astype(jnp.float32),
+                                     payload.chunk // 2 + 1, into)
 
     def decompress(self, payload) -> jnp.ndarray:
         spectrum = self.decompress_spectrum(payload)
-        return cfft.chunked_irfft(spectrum, payload.orig_len, payload.chunk)
+        with jax.named_scope("exchange.irfft"):
+            return cfft.chunked_irfft(spectrum, payload.orig_len, payload.chunk)
 
     def decompress_stacked(self, payload) -> jnp.ndarray:
         """StackedPayload -> ``(n_buckets, padded_size)`` time-domain matrix
@@ -314,7 +316,8 @@ class CompressorBackend:
         rows decode to exact zeros, so each row's prefix is bitwise-equal to
         the per-bucket ``decompress``."""
         spectrum = self.decompress_spectrum(payload)  # (B, max_chunks, f)
-        x = cfft.irfft_rows(spectrum, payload.chunk)
+        with jax.named_scope("exchange.irfft"):
+            x = cfft.irfft_rows(spectrum, payload.chunk)
         return x.reshape(spectrum.shape[0], -1)
 
 
@@ -327,37 +330,42 @@ class ReferenceBackend(CompressorBackend):
     name = "reference"
 
     def compress(self, cfg, x_flat: jnp.ndarray):
-        freqs, n = cfft.chunked_rfft(x_flat, cfg.chunk)
+        with jax.named_scope("exchange.rfft"):
+            freqs, n = cfft.chunked_rfft(x_flat, cfg.chunk)
         k = _keep_k(cfg)
         w = cfft.hermitian_weights(cfg.chunk)
-        re_p = jnp.real(freqs).astype(jnp.float32)
-        im_p = jnp.imag(freqs).astype(jnp.float32)
-        mag = _weighted_magnitude(re_p, im_p, w)
-        sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
-        if sel == "sort":
-            idx = sparsify.topk_select(mag, k)
-            tau = None
-        else:
-            # threshold selector (DESIGN.md §16): O(n) tau + one count-and-
-            # compact pass; slots come out index-ascending (pallas order)
-            tau = _selector_tau(cfg, mag, k, sel)
-            idx = selection.count_compact(mag, tau, k)
-        kept = packing.pack_by_indices(freqs, idx)
-        re, im = jnp.real(kept), jnp.imag(kept)
-        if cfg.quantize:
-            if tau is None:
-                quant = self._fit(cfg, re, im)
+        with jax.named_scope("exchange.select"):
+            re_p = jnp.real(freqs).astype(jnp.float32)
+            im_p = jnp.imag(freqs).astype(jnp.float32)
+            mag = _weighted_magnitude(re_p, im_p, w)
+            sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
+            if sel == "sort":
+                idx = sparsify.topk_select(mag, k)
+                tau = None
             else:
-                # fit over the PRE-truncation tau mask — the same set the
-                # pallas backend fits over, so cross-backend codes stay
-                # bitwise-equal under every selector (tie caveat as in
-                # PallasBackend.compress)
-                quant = self._fit_masked(cfg, re_p, im_p, mag >= tau)
-            re, im = q_encode(re, quant), q_encode(im, quant)
-        else:
-            quant = None
-        # int16 indices: 2049 rfft bins fit; halves the index wire bytes
-        return _payload_cls()(re, im, idx.astype(jnp.int16), quant, n, cfg.chunk)
+                # threshold selector (DESIGN.md §16): O(n) tau + one count-
+                # and-compact pass; slots come out index-ascending (pallas
+                # order)
+                tau = _selector_tau(cfg, mag, k, sel)
+                idx = selection.count_compact(mag, tau, k)
+        with jax.named_scope("exchange.pack"):
+            kept = packing.pack_by_indices(freqs, idx)
+            re, im = jnp.real(kept), jnp.imag(kept)
+            if cfg.quantize:
+                if tau is None:
+                    quant = self._fit(cfg, re, im)
+                else:
+                    # fit over the PRE-truncation tau mask — the same set the
+                    # pallas backend fits over, so cross-backend codes stay
+                    # bitwise-equal under every selector (tie caveat as in
+                    # PallasBackend.compress)
+                    quant = self._fit_masked(cfg, re_p, im_p, mag >= tau)
+                re, im = q_encode(re, quant), q_encode(im, quant)
+            else:
+                quant = None
+            # int16 indices: 2049 rfft bins fit; halves the index wire bytes
+            return _payload_cls()(re, im, idx.astype(jnp.int16), quant, n,
+                                  cfg.chunk)
 
     def _fit(self, cfg, re: jnp.ndarray, im: jnp.ndarray):
         if cfg.range_mode == "fixed":
@@ -403,43 +411,47 @@ class ReferenceBackend(CompressorBackend):
             x2d, c_b = args  # (max_chunks, chunk) rows, true chunk count
             # row-for-row the same transform the looped path runs via
             # cfft.chunked_rfft
-            freqs = cfft.rfft_rows(x2d)
-            re_p = jnp.real(freqs).astype(jnp.float32)
-            im_p = jnp.imag(freqs).astype(jnp.float32)
-            mag = _weighted_magnitude(re_p, im_p, w)
-            if sel == "sort":
-                idx = sparsify.topk_select(mag, k)
-                tau = None
-            else:
-                # per-row threshold selection is bucket-independent, so the
-                # stacked result matches the looped compress row-for-row
-                tau = _selector_tau(cfg, mag, k, sel)
-                idx = selection.count_compact(mag, tau, k)
-            kept = packing.pack_by_indices(freqs, idx)
-            re, im = jnp.real(kept), jnp.imag(kept)
-            if not cfg.quantize:
-                return re, im, idx
-            if cfg.range_mode == "fixed":
-                lo, hi = cfg.fixed_range
-                quant = fit_quantizer(lo, hi, _qcfg(cfg))
-            elif tau is None:
-                valid = (jnp.arange(c_max) < c_b)[:, None]
-                lo = jnp.minimum(jnp.where(valid, re, jnp.inf).min(),
-                                 jnp.where(valid, im, jnp.inf).min())
-                hi = jnp.maximum(jnp.where(valid, re, -jnp.inf).max(),
-                                 jnp.where(valid, im, -jnp.inf).max())
-                quant = fit_quantizer(lo, hi, _qcfg(cfg))
-            else:
-                # pre-truncation tau mask, with the all-zero PADDING rows
-                # (tau 0 -> mask all-true) excluded so the fit sees exactly
-                # what the looped per-bucket fit saw
-                m = (mag >= tau) & (jnp.arange(c_max) < c_b)[:, None]
-                lo = jnp.minimum(jnp.where(m, re_p, jnp.inf).min(),
-                                 jnp.where(m, im_p, jnp.inf).min())
-                hi = jnp.maximum(jnp.where(m, re_p, -jnp.inf).max(),
-                                 jnp.where(m, im_p, -jnp.inf).max())
-                quant = fit_quantizer(lo, hi, _qcfg(cfg))
-            return q_encode(re, quant), q_encode(im, quant), idx, quant
+            with jax.named_scope("exchange.rfft"):
+                freqs = cfft.rfft_rows(x2d)
+            with jax.named_scope("exchange.select"):
+                re_p = jnp.real(freqs).astype(jnp.float32)
+                im_p = jnp.imag(freqs).astype(jnp.float32)
+                mag = _weighted_magnitude(re_p, im_p, w)
+                if sel == "sort":
+                    idx = sparsify.topk_select(mag, k)
+                    tau = None
+                else:
+                    # per-row threshold selection is bucket-independent, so
+                    # the stacked result matches the looped compress
+                    # row-for-row
+                    tau = _selector_tau(cfg, mag, k, sel)
+                    idx = selection.count_compact(mag, tau, k)
+            with jax.named_scope("exchange.pack"):
+                kept = packing.pack_by_indices(freqs, idx)
+                re, im = jnp.real(kept), jnp.imag(kept)
+                if not cfg.quantize:
+                    return re, im, idx
+                if cfg.range_mode == "fixed":
+                    lo, hi = cfg.fixed_range
+                    quant = fit_quantizer(lo, hi, _qcfg(cfg))
+                elif tau is None:
+                    valid = (jnp.arange(c_max) < c_b)[:, None]
+                    lo = jnp.minimum(jnp.where(valid, re, jnp.inf).min(),
+                                     jnp.where(valid, im, jnp.inf).min())
+                    hi = jnp.maximum(jnp.where(valid, re, -jnp.inf).max(),
+                                     jnp.where(valid, im, -jnp.inf).max())
+                    quant = fit_quantizer(lo, hi, _qcfg(cfg))
+                else:
+                    # pre-truncation tau mask, with the all-zero PADDING rows
+                    # (tau 0 -> mask all-true) excluded so the fit sees exactly
+                    # what the looped per-bucket fit saw
+                    m = (mag >= tau) & (jnp.arange(c_max) < c_b)[:, None]
+                    lo = jnp.minimum(jnp.where(m, re_p, jnp.inf).min(),
+                                     jnp.where(m, im_p, jnp.inf).min())
+                    hi = jnp.maximum(jnp.where(m, re_p, -jnp.inf).max(),
+                                     jnp.where(m, im_p, -jnp.inf).max())
+                    quant = fit_quantizer(lo, hi, _qcfg(cfg))
+                return q_encode(re, quant), q_encode(im, quant), idx, quant
 
         x3 = stacked.reshape(n_buckets, c_max, cfg.chunk)
         if cfg.quantize:
@@ -471,26 +483,10 @@ class PallasBackend(CompressorBackend):
     name = "pallas"
 
     def compress(self, cfg, x_flat: jnp.ndarray):
-        freqs, n = cfft.chunked_rfft(x_flat, cfg.chunk)
-        re = jnp.real(freqs).astype(jnp.float32)
-        im = jnp.imag(freqs).astype(jnp.float32)
+        with jax.named_scope("exchange.rfft"):
+            freqs, n = cfft.chunked_rfft(x_flat, cfg.chunk)
         k = _keep_k(cfg)
         w = cfft.hermitian_weights(cfg.chunk)
-        mag = _weighted_magnitude(re, im, w)
-        sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
-
-        if not cfg.quantize:
-            _log_once("pallas compress: quantize=False -> per-stage "
-                      "threshold+pack kernels (no fused quantization)")
-            tau, mag = _pallas_select(cfg, mag, k, sel)
-            mvals, idx = ops.pack_threshold(mag, tau, k)  # width pad_k(k)
-            valid = mvals != 0
-            re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
-            im_k = jnp.take_along_axis(im, idx, axis=-1) * valid
-            return _payload_cls()(
-                re_k[:, :k], im_k[:, :k], idx[:, :k].astype(jnp.int16),
-                None, n, cfg.chunk)
-
         # ONE threshold pass defines the kept set; its tau and the magnitude
         # plane it was computed on go to the fused kernel (no second
         # in-kernel search, no in-register recompute), so the mask the kernel
@@ -503,29 +499,48 @@ class PallasBackend(CompressorBackend):
         # index-ascending, and the fit below covers the full pre-truncation
         # mask — exactly what the reference selector path fits (DESIGN.md
         # §16).
-        tau, mag = _pallas_select(cfg, mag, k, sel)
-        if cfg.range_mode == "fixed":
-            lo, hi = cfg.fixed_range
-            quant = fit_quantizer(lo, hi, _qcfg(cfg))
-        else:
-            mask = mag >= tau
-            lo = jnp.minimum(jnp.where(mask, re, jnp.inf).min(),
-                             jnp.where(mask, im, jnp.inf).min())
-            hi = jnp.maximum(jnp.where(mask, re, -jnp.inf).max(),
-                             jnp.where(mask, im, -jnp.inf).max())
-            quant = fit_quantizer(lo, hi, _qcfg(cfg))
+        with jax.named_scope("exchange.select"):
+            re = jnp.real(freqs).astype(jnp.float32)
+            im = jnp.imag(freqs).astype(jnp.float32)
+            mag = _weighted_magnitude(re, im, w)
+            sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
+            tau, mag = _pallas_select(cfg, mag, k, sel)
 
-        rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
-            re, im, mag, quant.eps, quant.p_codes, tau,
-            k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
-        # slice the tile padding off: payload layout == reference layout.
-        # Under the threshold selectors a kept surplus (ties, a sampled tau)
-        # truncates the highest-INDEX kept slots here — bucketSelect's
-        # static-budget semantics, and what the reference's count_compact
-        # does.
-        return _payload_cls()(
-            rec[:, :k], imc[:, :k], idx[:, :k].astype(jnp.int16),
-            quant, n, cfg.chunk)
+        if not cfg.quantize:
+            _log_once("pallas compress: quantize=False -> per-stage "
+                      "threshold+pack kernels (no fused quantization)")
+            with jax.named_scope("exchange.pack"):
+                mvals, idx = ops.pack_threshold(mag, tau, k)  # width pad_k(k)
+                valid = mvals != 0
+                re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
+                im_k = jnp.take_along_axis(im, idx, axis=-1) * valid
+                return _payload_cls()(
+                    re_k[:, :k], im_k[:, :k], idx[:, :k].astype(jnp.int16),
+                    None, n, cfg.chunk)
+
+        with jax.named_scope("exchange.pack"):
+            if cfg.range_mode == "fixed":
+                lo, hi = cfg.fixed_range
+                quant = fit_quantizer(lo, hi, _qcfg(cfg))
+            else:
+                mask = mag >= tau
+                lo = jnp.minimum(jnp.where(mask, re, jnp.inf).min(),
+                                 jnp.where(mask, im, jnp.inf).min())
+                hi = jnp.maximum(jnp.where(mask, re, -jnp.inf).max(),
+                                 jnp.where(mask, im, -jnp.inf).max())
+                quant = fit_quantizer(lo, hi, _qcfg(cfg))
+
+            rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
+                re, im, mag, quant.eps, quant.p_codes, tau,
+                k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
+            # slice the tile padding off: payload layout == reference layout.
+            # Under the threshold selectors a kept surplus (ties, a sampled
+            # tau) truncates the highest-INDEX kept slots here —
+            # bucketSelect's static-budget semantics, and what the
+            # reference's count_compact does.
+            return _payload_cls()(
+                rec[:, :k], imc[:, :k], idx[:, :k].astype(jnp.int16),
+                quant, n, cfg.chunk)
 
     def compress_stacked(self, cfg, stacked: jnp.ndarray, sizes):
         """ONE kernel launch for every bucket: all bucket rows ride a single
@@ -539,62 +554,65 @@ class PallasBackend(CompressorBackend):
         c_max = padded // cfg.chunk
         rows = n_buckets * c_max
         x2d = stacked.reshape(rows, cfg.chunk).astype(jnp.float32)
-        freqs = cfft.rfft_rows(x2d)
-        re = jnp.real(freqs).astype(jnp.float32)
-        im = jnp.imag(freqs).astype(jnp.float32)
+        with jax.named_scope("exchange.rfft"):
+            freqs = cfft.rfft_rows(x2d)
         k = _keep_k(cfg)
         w = cfft.hermitian_weights(cfg.chunk)
-        mag = _weighted_magnitude(re, im, w)
-        sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
+        # same one-threshold contract as the looped compress, batched over
+        # every bucket's chunks in one threshold-kernel launch
+        with jax.named_scope("exchange.select"):
+            re = jnp.real(freqs).astype(jnp.float32)
+            im = jnp.imag(freqs).astype(jnp.float32)
+            mag = _weighted_magnitude(re, im, w)
+            sel = selection.resolve_selector(cfg.selector, mag.shape[-1])
+            tau, mag = _pallas_select(cfg, mag, k, sel)
 
         if not cfg.quantize:
             _log_once("pallas compress_stacked: quantize=False -> per-stage "
                       "threshold+pack kernels (no fused quantization)")
-            tau, mag = _pallas_select(cfg, mag, k, sel)
-            mvals, idx = ops.pack_threshold(mag, tau, k)
-            valid = mvals != 0
-            re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
-            im_k = jnp.take_along_axis(im, idx, axis=-1) * valid
-            return _stacked_cls()(
-                re_k[:, :k].reshape(n_buckets, c_max, k),
-                im_k[:, :k].reshape(n_buckets, c_max, k),
-                idx[:, :k].astype(jnp.int16).reshape(n_buckets, c_max, k),
-                None, sizes, cfg.chunk)
+            with jax.named_scope("exchange.pack"):
+                mvals, idx = ops.pack_threshold(mag, tau, k)
+                valid = mvals != 0
+                re_k = jnp.take_along_axis(re, idx, axis=-1) * valid
+                im_k = jnp.take_along_axis(im, idx, axis=-1) * valid
+                return _stacked_cls()(
+                    re_k[:, :k].reshape(n_buckets, c_max, k),
+                    im_k[:, :k].reshape(n_buckets, c_max, k),
+                    idx[:, :k].astype(jnp.int16).reshape(n_buckets, c_max, k),
+                    None, sizes, cfg.chunk)
 
-        # same one-threshold contract as the looped compress, batched over
-        # every bucket's chunks in one threshold-kernel launch
-        tau, mag = _pallas_select(cfg, mag, k, sel)
-        if cfg.range_mode == "fixed":
-            lo = jnp.full((n_buckets,), cfg.fixed_range[0], jnp.float32)
-            hi = jnp.full((n_buckets,), cfg.fixed_range[1], jnp.float32)
-        else:
-            # per-bucket fit over the kept set; padding rows (all-zero chunks,
-            # tau 0, mask all-true) are excluded so the fit sees exactly the
-            # values the looped per-bucket fit saw
-            mask = ((mag >= tau)
-                    & _valid_chunk_mask(sizes, c_max, cfg.chunk).reshape(
-                        rows, 1))
-            m3 = mask.reshape(n_buckets, c_max, -1)
-            re3 = re.reshape(n_buckets, c_max, -1)
-            im3 = im.reshape(n_buckets, c_max, -1)
-            lo = jnp.minimum(
-                jnp.where(m3, re3, jnp.inf).min(axis=(1, 2)),
-                jnp.where(m3, im3, jnp.inf).min(axis=(1, 2)))
-            hi = jnp.maximum(
-                jnp.where(m3, re3, -jnp.inf).max(axis=(1, 2)),
-                jnp.where(m3, im3, -jnp.inf).max(axis=(1, 2)))
-        quant = _stack_quant(fit_quantizer(lo, hi, _qcfg(cfg)))
-        # per-bucket params -> per-row planes for the single fused launch
-        eps_rows = jnp.repeat(quant.eps.reshape(n_buckets), c_max)
-        p_rows = jnp.repeat(quant.p_codes.reshape(n_buckets), c_max)
-        rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
-            re, im, mag, eps_rows, p_rows, tau,
-            k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
-        return _stacked_cls()(
-            rec[:, :k].reshape(n_buckets, c_max, k),
-            imc[:, :k].reshape(n_buckets, c_max, k),
-            idx[:, :k].astype(jnp.int16).reshape(n_buckets, c_max, k),
-            quant, sizes, cfg.chunk)
+        with jax.named_scope("exchange.pack"):
+            if cfg.range_mode == "fixed":
+                lo = jnp.full((n_buckets,), cfg.fixed_range[0], jnp.float32)
+                hi = jnp.full((n_buckets,), cfg.fixed_range[1], jnp.float32)
+            else:
+                # per-bucket fit over the kept set; padding rows (all-zero chunks,
+                # tau 0, mask all-true) are excluded so the fit sees exactly the
+                # values the looped per-bucket fit saw
+                mask = ((mag >= tau)
+                        & _valid_chunk_mask(sizes, c_max, cfg.chunk).reshape(
+                            rows, 1))
+                m3 = mask.reshape(n_buckets, c_max, -1)
+                re3 = re.reshape(n_buckets, c_max, -1)
+                im3 = im.reshape(n_buckets, c_max, -1)
+                lo = jnp.minimum(
+                    jnp.where(m3, re3, jnp.inf).min(axis=(1, 2)),
+                    jnp.where(m3, im3, jnp.inf).min(axis=(1, 2)))
+                hi = jnp.maximum(
+                    jnp.where(m3, re3, -jnp.inf).max(axis=(1, 2)),
+                    jnp.where(m3, im3, -jnp.inf).max(axis=(1, 2)))
+            quant = _stack_quant(fit_quantizer(lo, hi, _qcfg(cfg)))
+            # per-bucket params -> per-row planes for the single fused launch
+            eps_rows = jnp.repeat(quant.eps.reshape(n_buckets), c_max)
+            p_rows = jnp.repeat(quant.p_codes.reshape(n_buckets), c_max)
+            rec, imc, idx, _tau = fused_compress.fused_compress_pallas(
+                re, im, mag, eps_rows, p_rows, tau,
+                k_keep=k, n_bits=cfg.n_bits, m_bits=cfg.m_bits)
+            return _stacked_cls()(
+                rec[:, :k].reshape(n_buckets, c_max, k),
+                imc[:, :k].reshape(n_buckets, c_max, k),
+                idx[:, :k].astype(jnp.int16).reshape(n_buckets, c_max, k),
+                quant, sizes, cfg.chunk)
 
     def decompress_stacked(self, payload) -> jnp.ndarray:
         if payload.quant is not None and payload.chunk == KERNEL_CHUNK:
@@ -603,10 +621,13 @@ class PallasBackend(CompressorBackend):
             eps_rows = jnp.repeat(payload.quant.eps.reshape(n_buckets), c_max)
             p_rows = jnp.repeat(
                 payload.quant.p_codes.reshape(n_buckets), c_max)
-            x2d = fused_decompress.fused_decompress_pallas(
-                payload.re.reshape(rows, k), payload.im.reshape(rows, k),
-                payload.idx.reshape(rows, k), eps_rows, p_rows,
-                m_bits=payload.quant.config.m_bits)
+            # the fused kernel runs its inverse FFT in the same pass: the
+            # whole launch counts as the fold
+            with jax.named_scope("exchange.fold"):
+                x2d = fused_decompress.fused_decompress_pallas(
+                    payload.re.reshape(rows, k), payload.im.reshape(rows, k),
+                    payload.idx.reshape(rows, k), eps_rows, p_rows,
+                    m_bits=payload.quant.config.m_bits)
             return x2d.reshape(n_buckets, c_max * KERNEL_CHUNK)
         if payload.quant is not None:
             _log_once(
@@ -621,24 +642,26 @@ class PallasBackend(CompressorBackend):
             p_rows = jnp.repeat(
                 payload.quant.p_codes.reshape(n_buckets), c_max)
             qcfg = payload.quant.config
-            re = range_quant.decode_pallas(
-                payload.re.reshape(rows, k), eps_rows, p_rows,
-                n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
-                    n_buckets, c_max, k)
-            im = range_quant.decode_pallas(
-                payload.im.reshape(rows, k), eps_rows, p_rows,
-                n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
-                    n_buckets, c_max, k)
+            with jax.named_scope("exchange.fold"):
+                re = range_quant.decode_pallas(
+                    payload.re.reshape(rows, k), eps_rows, p_rows,
+                    n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
+                        n_buckets, c_max, k)
+                im = range_quant.decode_pallas(
+                    payload.im.reshape(rows, k), eps_rows, p_rows,
+                    n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
+                        n_buckets, c_max, k)
             payload = _stacked_cls()(re, im, payload.idx, None, payload.sizes,
                                      payload.chunk, payload.has_im)
         return super().decompress_stacked(payload)
 
     def decompress(self, payload) -> jnp.ndarray:
         if payload.quant is not None and payload.chunk == KERNEL_CHUNK:
-            x2d = fused_decompress.fused_decompress_pallas(
-                payload.re, payload.im, payload.idx,
-                payload.quant.eps, payload.quant.p_codes,
-                m_bits=payload.quant.config.m_bits)
+            with jax.named_scope("exchange.fold"):  # with its inverse FFT
+                x2d = fused_decompress.fused_decompress_pallas(
+                    payload.re, payload.im, payload.idx,
+                    payload.quant.eps, payload.quant.p_codes,
+                    m_bits=payload.quant.config.m_bits)
             return x2d.reshape(-1)[: payload.orig_len].astype(jnp.float32)
         _log_once(
             "pallas decompress: payload is "
@@ -646,8 +669,9 @@ class PallasBackend(CompressorBackend):
                else f"chunked at {payload.chunk} != {KERNEL_CHUNK}")
             + " -> per-stage (quant_decode kernel + scatter + XLA irfft)")
         if payload.quant is not None:
-            re = ops.quant_decode(payload.re, payload.quant)
-            im = ops.quant_decode(payload.im, payload.quant)
+            with jax.named_scope("exchange.fold"):
+                re = ops.quant_decode(payload.re, payload.quant)
+                im = ops.quant_decode(payload.im, payload.quant)
             payload = _payload_cls()(
                 re, im, payload.idx, None, payload.orig_len, payload.chunk)
         return super().decompress(payload)

@@ -23,6 +23,14 @@ All modes share: grad -> [reduce] -> global-norm clip -> optimizer -> new
 state, with theta threaded statically (a theta-schedule change rebuilds the
 step — bounded recompiles, see core/schedules.py).
 
+Every operation of the step runs under one of three named scopes, which the
+compiled program keeps on each instruction and the device trace shows:
+``step.fwd_bwd`` (forward and backward; backward operations also carry
+JAX's ``transpose(...)``), ``step.exchange`` (the reducer, whose stages carry
+``exchange.*`` scopes of their own) and ``step.optimizer`` (the loss and
+metric means, clipping, the optimizer and the guard's commit).  Metadata
+only: the scopes change no operation.
+
 The compressed exchange is bucketed and transport-pluggable (DESIGN.md
 §8-§9): ``ReducerConfig.bucket_bytes`` splits the flat gradient into
 chunk-aligned buckets and ``ReducerConfig.transport`` picks the collective
@@ -187,8 +195,11 @@ def build_train_step(
         vg = _loss_and_grad(model, mesh_ctx)
 
         def step(state, batch):
-            (loss, metrics), grads = vg(state["params"], batch)
-            new_state, gnorm = _optimizer_update(opt_cfg, step_cfg, state, grads, lr_scale)
+            with jax.named_scope("step.fwd_bwd"):
+                (loss, metrics), grads = vg(state["params"], batch)
+            with jax.named_scope("step.optimizer"):
+                new_state, gnorm = _optimizer_update(
+                    opt_cfg, step_cfg, state, grads, lr_scale)
             metrics = dict(metrics, loss=loss, grad_norm=gnorm)
             return new_state, metrics
 
@@ -283,7 +294,8 @@ def build_train_step(
         step_no = state["step"]
         if ef:
             state = dict(state, residual=state["residual"][0])
-        (loss, metrics), grads = vg_inner(state["params"], batch)
+        with jax.named_scope("step.fwd_bwd"):
+            (loss, metrics), grads = vg_inner(state["params"], batch)
         if plan is not None and plan.nan_events:
             # deterministic gradient poisoning (FaultPlan.nan_grad): the
             # worker coordinate is the row-major linear index over the
@@ -295,49 +307,54 @@ def build_train_step(
                 lambda g: jnp.where(poison, jnp.asarray(jnp.nan, g.dtype), g),
                 grads)
         pay_ok = jnp.bool_(True)
-        if ef:
-            if resilient:
-                reduced, new_residual, pay_ok = reducer(
-                    grads, state["residual"], step=step_no)
-            else:
-                reduced, new_residual = reducer(grads, state["residual"])
-        else:
-            if resilient:
-                reduced, pay_ok = reducer(grads, step=step_no)
-            else:
-                reduced = reducer(grads)
-        loss = jax.lax.pmean(loss, manual)
-        metrics = jax.lax.pmean(metrics, manual)
-        new_state, gnorm = _optimizer_update(
-            opt_cfg, step_cfg, state, reduced, lr_scale)
-        if ef:
-            new_state["residual"] = new_residual
-        skipped = jnp.float32(0.0)
-        if guard:
-            # all-workers-agree finiteness flag: local gradient, reduced
-            # mean, residual update, and payload validation must all be
-            # sound EVERYWHERE — one pmin makes the verdict bitwise-
-            # replicated, so workers can never diverge on whether the
-            # update committed
-            ok_local = (pay_ok
-                        & faults_mod.tree_finite(grads)
-                        & faults_mod.tree_finite(reduced))
+        with jax.named_scope("step.exchange"):
             if ef:
-                ok_local = ok_local & jnp.isfinite(new_residual).all()
-            keep = jax.lax.pmin(ok_local.astype(jnp.int32), manual) > 0
-            # a skipped step commits nothing but the step counter: params
-            # and moments stay put, and the EF residual is QUARANTINED —
-            # carrying e_{t-1} over unchanged keeps the DGC recurrence on
-            # clean inputs instead of folding a poisoned error in
-            old_state = dict(state, step=state["step"] + 1)
-            new_state = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(keep, new, old),
-                new_state, old_state)
-            skipped = 1.0 - keep.astype(jnp.float32)
-        if ef:
-            new_state["residual"] = new_state["residual"][None]
-        metrics = dict(metrics, loss=loss, grad_norm=gnorm, skipped=skipped)
-        return new_state, metrics
+                if resilient:
+                    reduced, new_residual, pay_ok = reducer(
+                        grads, state["residual"], step=step_no)
+                else:
+                    reduced, new_residual = reducer(grads, state["residual"])
+            else:
+                if resilient:
+                    reduced, pay_ok = reducer(grads, step=step_no)
+                else:
+                    reduced = reducer(grads)
+        # one scope for the update and the guard's commit: XLA fuses AdamW's
+        # update with the guard's select, and a fusion carries the name of
+        # its root, so two scopes would read one of them as empty
+        with jax.named_scope("step.optimizer"):
+            loss = jax.lax.pmean(loss, manual)
+            metrics = jax.lax.pmean(metrics, manual)
+            new_state, gnorm = _optimizer_update(
+                opt_cfg, step_cfg, state, reduced, lr_scale)
+            if ef:
+                new_state["residual"] = new_residual
+            skipped = jnp.float32(0.0)
+            if guard:
+                # all-workers-agree finiteness flag: local gradient, reduced
+                # mean, residual update, and payload validation must all be
+                # sound EVERYWHERE — one pmin makes the verdict bitwise-
+                # replicated, so workers can never diverge on whether the
+                # update committed
+                ok_local = (pay_ok
+                            & faults_mod.tree_finite(grads)
+                            & faults_mod.tree_finite(reduced))
+                if ef:
+                    ok_local = ok_local & jnp.isfinite(new_residual).all()
+                keep = jax.lax.pmin(ok_local.astype(jnp.int32), manual) > 0
+                # a skipped step commits nothing but the step counter: params
+                # and moments stay put, and the EF residual is QUARANTINED —
+                # carrying e_{t-1} over unchanged keeps the DGC recurrence on
+                # clean inputs instead of folding a poisoned error in
+                old_state = dict(state, step=state["step"] + 1)
+                new_state = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(keep, new, old),
+                    new_state, old_state)
+                skipped = 1.0 - keep.astype(jnp.float32)
+            if ef:
+                new_state["residual"] = new_state["residual"][None]
+            metrics = dict(metrics, loss=loss, grad_norm=gnorm, skipped=skipped)
+            return new_state, metrics
 
     def state_in_specs(state_like):
         specs = jax.tree_util.tree_map(lambda _: P(), state_like)
