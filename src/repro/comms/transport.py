@@ -191,20 +191,6 @@ def _ordered_worker_mean(stacked: jnp.ndarray) -> jnp.ndarray:
     return acc * (1.0 / p)
 
 
-def _mean_spectrum(gathered, comp) -> jnp.ndarray:
-    """Mean of the gathered payloads' dense spectra in
-    ``_ordered_worker_mean``'s order, with each worker's kept coefficients
-    scattered onto ONE running spectrum: P gradient-sized spectra do not
-    fit beside a large model's training state."""
-    p = jax.tree_util.tree_leaves(gathered)[0].shape[0]
-    worker = lambda w: jax.tree_util.tree_map(lambda a: a[w], gathered)
-    acc = comp.decompress_spectrum(worker(0))
-    for w in range(1, p):
-        acc = comp.decompress_spectrum(worker(w), into=acc)
-    with jax.named_scope("exchange.fold"):
-        return acc * (1.0 / p)
-
-
 def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     """Seed exchange: all_gather one payload -> mean reconstruction.
 
@@ -213,7 +199,7 @@ def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     """
     gathered = jax.lax.all_gather(payload, axis)  # leading axis: workers
     if hasattr(comp, "decompress_spectrum"):
-        mean_spectrum = _mean_spectrum(gathered, comp)
+        mean_spectrum = comp.mean_spectrum(gathered)
         with jax.named_scope("exchange.irfft"):
             return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
     decompressed = jax.vmap(comp.decompress)(gathered)
@@ -420,7 +406,7 @@ class SequencedTransport(Transport):
         payload = _compress_stacked(flat, layout, comp, monitor)
         gathered = jax.lax.all_gather(payload, axis)  # ONE collective
         if hasattr(comp, "decompress_spectrum"):
-            mean = _mean_spectrum(gathered, comp)  # (B, max_chunks, f)
+            mean = comp.mean_spectrum(gathered)  # (B, max_chunks, f)
             return bucketing.unstack_buckets(
                 _irfft_rows(mean, layout.chunk), layout)
         recon = jax.vmap(comp.decompress_stacked)(gathered)  # (W, B, padded)
@@ -541,7 +527,7 @@ class HierarchicalTransport(Transport):
         node_payload = _compress_stacked(node_mean, layout, comp, monitor)
         gathered = jax.lax.all_gather(node_payload, node_ax)
         if hasattr(comp, "decompress_spectrum"):
-            mean = _mean_spectrum(gathered, comp)
+            mean = comp.mean_spectrum(gathered)
             return bucketing.unstack_buckets(
                 _irfft_rows(mean, layout.chunk), layout)
         recon = jax.vmap(comp.decompress_stacked)(gathered)

@@ -314,6 +314,11 @@ class FFTCompressor:
         dense spectrum ``into`` when one is given."""
         return self._backend.decompress_spectrum(payload, into)
 
+    def mean_spectrum(self, gathered) -> jnp.ndarray:
+        """Mean dense spectrum of P gathered payloads (leaves with a leading
+        worker axis), the workers folded in order."""
+        return self._backend.mean_spectrum(gathered)
+
     def decompress(self, payload: FFTPayload) -> jnp.ndarray:
         return self._backend.decompress(payload)
 

@@ -14,6 +14,7 @@ implements the entry points the compressor exposes:
     decompress(payload)              -> flat f32
     decompress_stacked(payload)      -> (n_buckets, padded) f32
     decompress_spectrum(payload)     -> dense complex spectrum (batch-aware)
+    mean_spectrum(gathered)          -> mean spectrum of P gathered payloads
     wire_bits(cfg, n)                -> static wire estimate (shared accounting)
 
 Backends (``FFTCompressorConfig.backend``):
@@ -25,7 +26,9 @@ Backends (``FFTCompressorConfig.backend``):
 * ``pallas``    — the fused device kernels: compress runs the bisection
   threshold + ``fused_compress`` (threshold -> pack -> quantize in one VMEM
   pass); decompress runs ``fused_decompress`` (dequantize -> Hermitian
-  scatter -> 4-step iFFT in one VMEM pass).  Stages with no kernel-eligible
+  scatter -> 4-step iFFT in one VMEM pass); the exchange's fold of gathered
+  payloads into the mean spectrum runs ``spectrum_fold`` (dequantize ->
+  scatter -> worker mean in one VMEM pass).  Stages with no kernel-eligible
   shape fall back per-stage with a logged reason.
 * ``auto``      — ``pallas`` when the platform compiles Mosaic
   (``runtime.mosaic_available``) and the config is kernel-eligible
@@ -52,6 +55,7 @@ bitwise (tests/test_engine.py).
 from __future__ import annotations
 
 import logging
+import math
 from typing import List, Sequence, Tuple
 
 import jax
@@ -65,7 +69,13 @@ from repro.core.quantizer import (
     encode as q_encode,
     fit_quantizer,
 )
-from repro.kernels import fused_compress, fused_decompress, ops, sampled_threshold
+from repro.kernels import (
+    fused_compress,
+    fused_decompress,
+    ops,
+    sampled_threshold,
+    spectrum_fold,
+)
 from repro.kernels.fft4step import CHUNK as KERNEL_CHUNK
 from repro.kernels.runtime import mosaic_available
 
@@ -207,6 +217,39 @@ def _scatter_spectrum(idx, re, im, f_bins: int, into=None) -> jnp.ndarray:
     return out.reshape(lead + (f_bins,))
 
 
+def _fold_refusal(payload):
+    """Why ``spectrum_fold`` cannot fold this payload, or ``None``."""
+    if payload.quant is None:
+        return "payload is unquantized (the fold kernel scatters codes)"
+    if payload.quant.config.n_bits > spectrum_fold.MAX_CODE_BITS:
+        return (f"{payload.quant.config.n_bits}-bit codes are not exact in "
+                "one bf16 MXU pass")
+    if payload.chunk % 256 or payload.chunk > KERNEL_CHUNK:
+        return (f"chunked at {payload.chunk}: the fold kernel takes chunks "
+                f"of a multiple of 256 up to {KERNEL_CHUNK}")
+    return None
+
+
+def _kernel_mean_spectrum(gathered) -> jnp.ndarray:
+    """``spectrum_fold`` over payload leaves with a leading worker axis ->
+    the complex mean spectrum ``(*lead, chunk//2 + 1)``."""
+    p, *lead, k = gathered.re.shape
+    rows = math.prod(lead)
+    q = gathered.quant
+    n_fits = q.eps.size // p  # one per worker, or one per bucket
+    if n_fits == 1:
+        eps, p_codes = q.eps.reshape(p), q.p_codes.reshape(p)
+    else:  # a stacked payload's buckets: each fit spread over its rows
+        per_row = lambda a: jnp.repeat(a.reshape(p, n_fits), rows // n_fits, axis=1)
+        eps, p_codes = per_row(q.eps), per_row(q.p_codes)
+    f_bins = gathered.chunk // 2 + 1
+    planes = [a.reshape(p, rows, k)
+              for a in (gathered.re, gathered.im, gathered.idx)]
+    spec = spectrum_fold.fold_mean_spectrum(
+        *planes, eps, p_codes, f_bins=f_bins, m_bits=q.config.m_bits)
+    return spec.reshape(*lead, f_bins)
+
+
 def _valid_chunk_mask(sizes, max_chunks: int, chunk: int) -> jnp.ndarray:
     # canonical padding-mask rule lives next to StackedPayload (deferred
     # import, same reason as _payload_cls)
@@ -290,12 +333,11 @@ class CompressorBackend:
         spectrum added onto the dense spectrum ``into`` (the gather
         transports fold P payloads into one buffer this way).
 
-        Shared by every backend: the dequantize+scatter is O(k) work that the
-        collectives vmap over the worker axis (comms/transport.py), so it
-        stays plain jnp — the kernel-fused win lives in compress/decompress.
-        Batch-aware over leading axes: accepts the monolithic (c, k) payload,
-        the stacked (n_buckets, max_chunks, k) payload, and any worker-vmap
-        of either (see ``_scatter_spectrum``).
+        The shared plain-jnp dequantize + scatter (the pallas backend runs
+        its fold kernel instead where the payload allows).  Batch-aware over
+        leading axes: accepts the monolithic (c, k) payload, the stacked
+        (n_buckets, max_chunks, k) payload, and any worker-vmap of either
+        (see ``_scatter_spectrum``).
         """
         with jax.named_scope("exchange.fold"):
             re, im = payload.re, payload.im
@@ -304,6 +346,21 @@ class CompressorBackend:
             return _scatter_spectrum(payload.idx, re.astype(jnp.float32),
                                      im.astype(jnp.float32),
                                      payload.chunk // 2 + 1, into)
+
+    def mean_spectrum(self, gathered) -> jnp.ndarray:
+        """Mean dense spectrum of P gathered payloads (every leaf carries a
+        leading worker axis), the workers added left to right onto one
+        running spectrum and multiplied by ``1/P`` — the order the
+        transports' bitwise contract rests on (``_ordered_worker_mean``).
+        One running spectrum: P gradient-sized spectra do not fit beside a
+        large model's training state."""
+        p = jax.tree_util.tree_leaves(gathered)[0].shape[0]
+        worker = lambda w: jax.tree_util.tree_map(lambda a: a[w], gathered)
+        acc = self.decompress_spectrum(worker(0))
+        for w in range(1, p):
+            acc = self.decompress_spectrum(worker(w), into=acc)
+        with jax.named_scope("exchange.fold"):
+            return acc * (1.0 / p)
 
     def decompress(self, payload) -> jnp.ndarray:
         spectrum = self.decompress_spectrum(payload)
@@ -474,8 +531,13 @@ class PallasBackend(CompressorBackend):
                 count so the payload layout matches ``reference`` exactly.
     decompress: ``fused_decompress_pallas`` (dequantize + Hermitian scatter +
                 4-step iFFT, one VMEM pass) when the payload is quantized and
-                chunked at 4096; otherwise per-stage (quant_decode kernel +
-                jnp scatter + XLA irfft) with a logged reason.
+                chunked at 4096; otherwise per-stage (the fold below + XLA
+                irfft) with a logged reason.
+    fold:       ``spectrum_fold_pallas`` (dequantize + scatter + worker
+                mean, one VMEM pass) for ``mean_spectrum`` and
+                ``decompress_spectrum`` when the payload is quantized to at
+                most 8 bits and chunked at a multiple of 256 up to 4096;
+                otherwise the shared jnp scatter with a logged reason.
 
     Packs kept coefficients in index-ascending (compaction) order.
     """
@@ -629,31 +691,37 @@ class PallasBackend(CompressorBackend):
                     payload.idx.reshape(rows, k), eps_rows, p_rows,
                     m_bits=payload.quant.config.m_bits)
             return x2d.reshape(n_buckets, c_max * KERNEL_CHUNK)
-        if payload.quant is not None:
-            _log_once(
-                f"pallas decompress_stacked: chunked at {payload.chunk} != "
-                f"{KERNEL_CHUNK} -> per-stage (per-row quant_decode kernel + "
-                "shared scatter + XLA irfft)")
-            from repro.kernels import range_quant
-
-            n_buckets, c_max, k = payload.re.shape
-            rows = n_buckets * c_max
-            eps_rows = jnp.repeat(payload.quant.eps.reshape(n_buckets), c_max)
-            p_rows = jnp.repeat(
-                payload.quant.p_codes.reshape(n_buckets), c_max)
-            qcfg = payload.quant.config
-            with jax.named_scope("exchange.fold"):
-                re = range_quant.decode_pallas(
-                    payload.re.reshape(rows, k), eps_rows, p_rows,
-                    n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
-                        n_buckets, c_max, k)
-                im = range_quant.decode_pallas(
-                    payload.im.reshape(rows, k), eps_rows, p_rows,
-                    n_bits=qcfg.n_bits, m_bits=qcfg.m_bits).reshape(
-                        n_buckets, c_max, k)
-            payload = _stacked_cls()(re, im, payload.idx, None, payload.sizes,
-                                     payload.chunk, payload.has_im)
+        _log_once(
+            "pallas decompress_stacked: payload is "
+            + ("unquantized" if payload.quant is None
+               else f"chunked at {payload.chunk} != {KERNEL_CHUNK}")
+            + " -> per-stage (fold + XLA irfft)")
         return super().decompress_stacked(payload)
+
+    def mean_spectrum(self, gathered) -> jnp.ndarray:
+        """Every worker's payload folded into the mean spectrum in ONE
+        ``spectrum_fold`` launch, bitwise equal to the shared jnp fold."""
+        reason = _fold_refusal(gathered)
+        if reason is not None:
+            _log_once(f"pallas mean_spectrum: {reason} -> shared jnp scatter")
+            return super().mean_spectrum(gathered)
+        with jax.named_scope("exchange.fold"):
+            return _kernel_mean_spectrum(gathered)
+
+    def decompress_spectrum(self, payload, into=None) -> jnp.ndarray:
+        """The fold kernel with one worker (the psum-shaped transports, the
+        per-stage decompress and the serve publisher).  A running spectrum
+        ``into`` is only ever given by the shared worker loop, which runs
+        where the kernel refused: it keeps the jnp scatter."""
+        reason = _fold_refusal(payload)
+        if into is not None or reason is not None:
+            if reason is not None:
+                _log_once(f"pallas decompress_spectrum: {reason} -> shared "
+                          "jnp scatter")
+            return super().decompress_spectrum(payload, into)
+        one = jax.tree_util.tree_map(lambda a: a[None], payload)
+        with jax.named_scope("exchange.fold"):
+            return _kernel_mean_spectrum(one)
 
     def decompress(self, payload) -> jnp.ndarray:
         if payload.quant is not None and payload.chunk == KERNEL_CHUNK:
@@ -667,13 +735,7 @@ class PallasBackend(CompressorBackend):
             "pallas decompress: payload is "
             + ("unquantized" if payload.quant is None
                else f"chunked at {payload.chunk} != {KERNEL_CHUNK}")
-            + " -> per-stage (quant_decode kernel + scatter + XLA irfft)")
-        if payload.quant is not None:
-            with jax.named_scope("exchange.fold"):
-                re = ops.quant_decode(payload.re, payload.quant)
-                im = ops.quant_decode(payload.im, payload.quant)
-            payload = _payload_cls()(
-                re, im, payload.idx, None, payload.orig_len, payload.chunk)
+            + " -> per-stage (fold + XLA irfft)")
         return super().decompress(payload)
 
 
@@ -719,6 +781,16 @@ class AutoBackend(CompressorBackend):
         if mosaic_available():
             return self._pallas.decompress_stacked(payload)
         return self._reference.decompress_stacked(payload)
+
+    def decompress_spectrum(self, payload, into=None) -> jnp.ndarray:
+        if mosaic_available():
+            return self._pallas.decompress_spectrum(payload, into)
+        return self._reference.decompress_spectrum(payload, into)
+
+    def mean_spectrum(self, gathered) -> jnp.ndarray:
+        if mosaic_available():
+            return self._pallas.mean_spectrum(gathered)
+        return self._reference.mean_spectrum(gathered)
 
 
 _BACKENDS = {

@@ -23,6 +23,7 @@ from repro.kernels import (
     pack,
     range_quant,
     sampled_threshold,
+    spectrum_fold,
     topk_threshold,
 )
 
@@ -48,6 +49,17 @@ def one_chip():
 
 
 F32, I32, I16, U8 = jnp.float32, jnp.int32, jnp.int16, jnp.uint8
+
+
+def _fold_case(workers, per_row):
+    q = (workers, ROWS) if per_row else (workers,)
+    payload = (workers, ROWS, K_KEEP)
+    return (
+        lambda rec, imc, idx, eps, p: spectrum_fold.spectrum_fold_pallas(
+            rec, imc, idx, eps, p, f_bins=BINS, interpret=False),
+        [(payload, U8), (payload, U8), (payload, I16), (q, F32), (q, I32)])
+
+
 CASES = {
     "fused_compress_per_row_tau": (
         lambda re, im, mag, eps, p, tau: fused_compress.fused_compress_pallas(
@@ -69,6 +81,8 @@ CASES = {
             rec, imc, idx, eps, p, interpret=False),
         [((ROWS, K_KEEP), U8), ((ROWS, K_KEEP), U8), ((ROWS, K_KEEP), I16),
          ((), F32), ((), I32)]),
+    **{f"spectrum_fold_p{w}_{'per_row' if per_row else 'scalar'}":
+       _fold_case(w, per_row) for w in (1, 4) for per_row in (False, True)},
     "sampled_threshold": (
         lambda mag, lo, hi: sampled_threshold.sampled_threshold_pallas(
             mag, lo, hi, k=K_KEEP, interpret=False),
@@ -103,3 +117,32 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+def test_mean_spectrum_compiles_to_the_fold_kernel_for_v5e(one_chip, monkeypatch):
+    """The transports' fold, compiled for the described chip with Mosaic on,
+    is the ``spectrum_fold_pallas`` launch under ``exchange.fold``: no sort
+    and no scatter (XLA sorts the pairs of a scatter this large, above 2**20
+    kept coefficients)."""
+    import re
+
+    from repro.core.compressor import FFTCompressor, FFTCompressorConfig, StackedPayload
+    from repro.core.quantizer import FittedQuantizer, RangeQuantConfig
+    from repro.kernels import engine, runtime
+
+    monkeypatch.setattr(engine, "mosaic_available", lambda: True)
+    monkeypatch.setattr(runtime, "mosaic_available", lambda: True)
+    workers, rows = 2, 2048
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    plane = (workers, 1, rows, K_KEEP)
+    quant = FittedQuantizer(RangeQuantConfig(8, 3), *(
+        arg((workers, 1, 1, 1), d) for d in (F32, I32, F32, F32)))
+    gathered = StackedPayload(arg(plane, U8), arg(plane, U8), arg(plane, I16),
+                              quant, (rows * 4096,), 4096)
+    comp = FFTCompressor(FFTCompressorConfig(backend="auto"))
+    text = jax.jit(comp.mean_spectrum).lower(
+        gathered).compile().as_text()
+    launches = [line for line in text.splitlines()
+                if re.match(r"\s*(ROOT )?%spectrum_fold_pallas", line)]
+    assert len(launches) == 1 and "/exchange.fold/" in launches[0], launches
+    assert not re.search(r"= \S+ (sort|scatter)\(", text)
