@@ -61,7 +61,7 @@ import jax.numpy as jnp
 
 from repro.comms import bucketing, collectives, scheduler
 from repro.comms import faults as faults_mod
-from repro.comms.transport import TRANSPORT_NAMES, get_transport
+from repro.comms.transport import TRANSPORT_NAMES, collective, get_transport
 from repro.core import baselines as B
 from repro.core.compressor import (
     FFTCompressor,
@@ -240,7 +240,7 @@ class ReducerConfig:
 
 
 def _mean_over(x, axis):
-    return jax.lax.pmean(x, axis)
+    return collective(jax.lax.pmean, x, axis)
 
 
 def _make_compressor(config: ReducerConfig):

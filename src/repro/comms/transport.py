@@ -99,7 +99,7 @@ from repro.comms import bucketing
 from repro.comms.collectives import axis_size
 from repro.core import fft as cfft
 
-__all__ = ["Transport", "get_transport", "TRANSPORT_NAMES", "two_level_axes"]
+__all__ = ["Transport", "get_transport", "TRANSPORT_NAMES", "two_level_axes", "collective"]
 
 TRANSPORT_NAMES = ("allgather", "sequenced", "psum", "hierarchical",
                    "reduce_scatter")
@@ -197,7 +197,7 @@ def _gather_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
     For spectral compressors the mean is taken in the frequency domain and a
     single inverse FFT recovers the time-domain mean (FFT linearity).
     """
-    gathered = jax.lax.all_gather(payload, axis)  # leading axis: workers
+    gathered = collective(jax.lax.all_gather, payload, axis)  # leading axis: workers
     if hasattr(comp, "decompress_spectrum"):
         mean_spectrum = comp.mean_spectrum(gathered)
         with jax.named_scope("exchange.irfft"):
@@ -220,11 +220,11 @@ def _psum_mean_payload(payload, comp, axis: str) -> jnp.ndarray:
         spec = comp.decompress_spectrum(payload)
         # psum real/imag planes separately: complex psum support varies by
         # backend, and two f32 reductions lower to one fused collective anyway
-        summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), axis)
+        summed = collective(jax.lax.psum, jnp.stack([spec.real, spec.imag]), axis)
         mean_spectrum = (summed[0] + 1j * summed[1]) * inv_p
         with jax.named_scope("exchange.irfft"):
             return cfft.chunked_irfft(mean_spectrum, payload.orig_len, payload.chunk)
-    return jax.lax.psum(comp.decompress(payload), axis) * inv_p
+    return collective(jax.lax.psum, comp.decompress(payload), axis) * inv_p
 
 
 class Transport:
@@ -404,7 +404,7 @@ class SequencedTransport(Transport):
             return super()._exchange_flat(flat, layout, comp, axis, stacked,
                                           monitor=monitor)
         payload = _compress_stacked(flat, layout, comp, monitor)
-        gathered = jax.lax.all_gather(payload, axis)  # ONE collective
+        gathered = collective(jax.lax.all_gather, payload, axis)  # ONE collective
         if hasattr(comp, "decompress_spectrum"):
             mean = comp.mean_spectrum(gathered)  # (B, max_chunks, f)
             return bucketing.unstack_buckets(
@@ -444,11 +444,11 @@ class SpectrumPsumTransport(Transport):
         inv_p = 1.0 / axis_size(axis)
         if hasattr(comp, "decompress_spectrum"):
             spec = comp.decompress_spectrum(payload)  # (B, max_chunks, f)
-            summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), axis)
+            summed = collective(jax.lax.psum, jnp.stack([spec.real, spec.imag]), axis)
             mean = (summed[0] + 1j * summed[1]) * inv_p
             return bucketing.unstack_buckets(
                 _irfft_rows(mean, layout.chunk), layout)
-        summed = jax.lax.psum(comp.decompress_stacked(payload), axis)
+        summed = collective(jax.lax.psum, comp.decompress_stacked(payload), axis)
         return bucketing.unstack_buckets(summed * inv_p, layout)
 
     def _roundtrip_flat(self, flat, layout, comp, stacked=True):
@@ -498,7 +498,7 @@ class HierarchicalTransport(Transport):
         # loop fallback psums the raw time-domain buckets (== the spectra
         # psum by FFT linearity, same dense wire), then compresses the node
         # mean once per island
-        node_means = [jax.lax.psum(b, local_ax) * inv_l for b in buckets]
+        node_means = [collective(jax.lax.psum, b, local_ax) * inv_l for b in buckets]
         node_payloads = _compress_all(node_means, comp, monitor)
         return [_gather_mean_payload(p, comp, node_ax) for p in node_payloads]
 
@@ -514,18 +514,18 @@ class HierarchicalTransport(Transport):
             x3 = rows.reshape(layout.n_buckets, -1, layout.chunk)
             with jax.named_scope("exchange.rfft"):
                 spec = cfft.rfft_rows(x3)  # DENSE spectra — no top-k
-            summed = jax.lax.psum(jnp.stack([spec.real, spec.imag]), local_ax)
+            summed = collective(jax.lax.psum, jnp.stack([spec.real, spec.imag]), local_ax)
             node_mean = bucketing.unstack_buckets(
                 _irfft_rows((summed[0] + 1j * summed[1]) * inv_l, layout.chunk),
                 layout)
         else:
             node_mean = bucketing.unstack_buckets(
-                jax.lax.psum(rows, local_ax) * inv_l, layout)
+                collective(jax.lax.psum, rows, local_ax) * inv_l, layout)
         # compress ONCE per island: this payload is the only thing the
         # inter-node fabric carries (every island worker holds the same
         # node_mean after the psum, so the fabric sees one copy per node)
         node_payload = _compress_stacked(node_mean, layout, comp, monitor)
-        gathered = jax.lax.all_gather(node_payload, node_ax)
+        gathered = collective(jax.lax.all_gather, node_payload, node_ax)
         if hasattr(comp, "decompress_spectrum"):
             mean = comp.mean_spectrum(gathered)
             return bucketing.unstack_buckets(
@@ -590,14 +590,14 @@ class ReduceScatterTransport(Transport):
             planes = jnp.concatenate(
                 [planes, jnp.zeros((pad_rows,) + planes.shape[1:],
                                    planes.dtype)])
-        shard = jax.lax.psum_scatter(
-            planes, axis, scatter_dimension=0, tiled=True)  # (B'/P, 2, c, f)
+        shard = collective(jax.lax.psum_scatter, planes, axis,
+                           scatter_dimension=0, tiled=True)  # (B'/P, 2, c, f)
         if hasattr(comp, "decompress_spectrum"):
             mean_spec = (shard[:, 0] + 1j * shard[:, 1]) * inv_p
             rows = _irfft_rows(mean_spec, layout.chunk)  # (B'/P, padded)
         else:
             rows = shard[:, 0] * inv_p
-        full = jax.lax.all_gather(rows, axis, tiled=True)  # (B', padded)
+        full = collective(jax.lax.all_gather, rows, axis, tiled=True)  # (B', padded)
         return bucketing.unstack_buckets(full[:b], layout)
 
     def _roundtrip_flat(self, flat, layout, comp, stacked=True):
@@ -606,6 +606,14 @@ class ReduceScatterTransport(Transport):
         payload = _compress_stacked(flat, layout, comp)
         return bucketing.unstack_buckets(
             comp.decompress_stacked(payload), layout)
+
+
+def collective(op, *args, **kwargs):
+    """``op(*args, **kwargs)`` under the ``exchange.collective`` scope: the
+    device trace names the collective's time apart from the exchange's
+    stages (the scope is metadata; the compiled program is the same)."""
+    with jax.named_scope("exchange.collective"):
+        return op(*args, **kwargs)
 
 
 def _resplit(flat: jnp.ndarray, sizes: List[int]) -> List[jnp.ndarray]:

@@ -1,5 +1,5 @@
 """internlm2-20b [dense]: 48L, d_model=6144, 48H (GQA kv=8), d_ff=16384,
-vocab=92544.  [arXiv:2403.17297; hf]"""
+vocab=92544, RMSNorm eps 1e-5.  [arXiv:2403.17297; hf internlm/internlm2-20b]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -13,4 +13,5 @@ CONFIG = ArchConfig(
     d_ff=16384,
     vocab_size=92544,
     rope_theta=1e6,
+    norm_eps=1e-5,
 )

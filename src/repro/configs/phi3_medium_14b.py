@@ -1,5 +1,6 @@
 """phi3-medium-14b [dense]: 40L, d_model=5120, 40H (GQA kv=10), d_ff=17920,
-vocab=100352 — RoPE SwiGLU GQA.  [arXiv:2404.14219; unverified]"""
+vocab=32064, RMSNorm eps 1e-5 — RoPE SwiGLU GQA.  [arXiv:2404.14219;
+hf microsoft/Phi-3-medium-4k-instruct]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -11,5 +12,6 @@ CONFIG = ArchConfig(
     n_kv_heads=10,
     head_dim=128,
     d_ff=17920,
-    vocab_size=100352,
+    vocab_size=32064,
+    norm_eps=1e-5,
 )

@@ -5,11 +5,14 @@ The step runs under three named scopes (``step.fwd_bwd``, ``step.exchange``,
 (``exchange.rfft``, ``.select``, ``.pack``, ``.fold``, ``.irfft``).  XLA
 keeps each instruction's scope path in its ``op_name`` metadata, fusions
 carrying their root's, and the benchmark reads per-stage device time from
-those names.  The collectives, and the flattening of the gradient around
-them, are the exchange's own time, outside its stages.  These tests compile
-tiny steps on the CPU and read the names back from ``compiled.as_text()``.
+those names.  The exchange's collectives carry ``exchange.collective`` and
+no stage; the flattening of the gradient around them is the exchange's own
+time, outside its stages.  These tests compile tiny steps on the CPU (on
+one device, and on four in a child process) and read the names back from
+``compiled.as_text()``.
 """
 
+import json
 import re
 
 import jax
@@ -25,12 +28,15 @@ from repro.optim import OptConfig
 from repro.train import init_state
 from repro.train.step import StepConfig, build_train_step
 
+from helpers import REPO, run_with_devices
+
 TINY = ArchConfig(name="tiny", family="dense", n_layers=1, d_model=64, n_heads=4,
                   n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=64)
 
 STEP_SCOPES = ("step.fwd_bwd", "step.exchange", "step.optimizer")
 EXCHANGE_STAGES = ("exchange.rfft", "exchange.select", "exchange.pack",
                    "exchange.fold", "exchange.irfft")
+COLLECTIVE_SCOPE = "exchange.collective"
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute",
                "all-to-all")
 # operations that do the step's work: each must carry exactly one step scope
@@ -42,7 +48,7 @@ STAGED = ("fft", "sort", "scatter", "custom-call")
 COMPILER_ROOTS = {"copy", "convert", "transpose", "bitcast", "broadcast",
                   "reshape", "slice", "concatenate", "pad", "reduce-window"}
 
-_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (?:\([^=]*?\)|\S+) ([a-z][a-z0-9\-]*)\((\)?)")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (?:\([^=]*?\)|\S+) ([a-z][a-z0-9\-]*)\((\)?)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) ")
@@ -79,16 +85,19 @@ def components(path: str):
     return path.split("/")
 
 
-def _compile(mode: str, reducer=None) -> str:
+def _compile(mode: str, reducer=None, rows: int = 2, lowered: bool = False) -> str:
     model = LM(TINY)
     opt = OptConfig(kind="adamw", lr=1e-3)
     mesh = make_local_mesh()
     step_cfg = StepConfig(mode=mode, reducer=reducer)
-    batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32) for k in ("tokens", "targets")}
+    batch = {k: jax.ShapeDtypeStruct((rows, 32), jnp.int32) for k in ("tokens", "targets")}
     state = jax.eval_shape(lambda k: init_state(k, model, opt), jax.random.PRNGKey(0))
     step = build_train_step(model, opt, step_cfg, mesh, batch)
     with compat.set_mesh(mesh):
-        return step.lower(state, batch).compile().as_text()
+        low = step.lower(state, batch)
+        if lowered:  # the program as JAX writes it, before XLA's passes
+            return low.as_text(dialect="hlo", debug_info=True)
+        return low.compile().as_text()
 
 
 def _fft(transport, **kw):
@@ -140,17 +149,17 @@ def test_exchange_operations_carry_their_stage(case):
         if path is None or op not in WORK + COLLECTIVES:
             continue
         parts = components(path)
-        stages = [c for c in parts if c.startswith("exchange.")]
-        assert len(stages) <= 1, (name, path)  # stages never nest
-        assert set(stages) <= set(EXCHANGE_STAGES), (name, path)
-        if stages:
+        named = [c for c in parts if c.startswith("exchange.")]
+        assert len(named) <= 1, (name, path)  # stages never nest
+        assert set(named) <= set(EXCHANGE_STAGES) | {COLLECTIVE_SCOPE}, (name, path)
+        if named:
             assert "step.exchange" in parts, (name, path)
-            seen.add(stages[0])
+            seen.add(named[0])
         if "step.exchange" in parts and op in STAGED:
-            assert stages, (name, op, path)
-        if op in COLLECTIVES:
-            assert not stages, (name, op, path)
-    assert seen == set(EXCHANGE_STAGES), seen
+            assert set(named) & set(EXCHANGE_STAGES), (name, op, path)
+        if op in COLLECTIVES and "step.exchange" in parts:
+            assert named == [COLLECTIVE_SCOPE], (name, op, path)
+    assert seen - {COLLECTIVE_SCOPE} == set(EXCHANGE_STAGES), seen
 
 
 @pytest.mark.parametrize("case", ["pjit", "dense", "fft_allgather_sort"])
@@ -170,6 +179,53 @@ def test_backward_operations_carry_transpose(case):
 
 
 def test_dense_exchange_has_no_stage():
+    # the dense mean is one collective: its scope and nothing else
     stages = {c for _, op, path, _, _ in compiled("dense") if path
               for c in components(path) if c.startswith("exchange.")}
-    assert stages == set()
+    assert stages == {COLLECTIVE_SCOPE}
+
+
+FOUR_DEVICE_CASES = ("dense", "fft_allgather_sort", "fft_psum_bucketed")
+_FOUR_DEVICES = """
+import json, sys
+sys.path[:0] = [{tests!r}]
+import test_step_scopes as t
+print(json.dumps({{case: [t._compile(*t.STEPS[case], rows=4, lowered=True),
+                         t._compile(*t.STEPS[case], rows=4)]
+                  for case in t.FOUR_DEVICE_CASES}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_steps():
+    """Per case, the instructions of the four-device step as lowered and as
+    compiled."""
+    out = run_with_devices(_FOUR_DEVICES.format(tests=f"{REPO}/tests"), devices=4)
+    return {case: [instructions(text) for text in texts]
+            for case, texts in json.loads(out.strip().splitlines()[-1]).items()}
+
+
+@pytest.mark.parametrize("case", FOUR_DEVICE_CASES)
+def test_collectives_across_four_devices_carry_the_collective_scope(
+        four_device_steps, case):
+    lowered, compiled_step = four_device_steps[case]
+    kinds = set()
+    for name, op, path, _, _ in lowered:
+        if op not in COLLECTIVES:
+            continue
+        parts = components(path)
+        named = [c for c in parts if c.startswith("exchange.")]
+        if "step.exchange" in parts:
+            # the exchange's own collective: its scope, and no stage
+            assert named == [COLLECTIVE_SCOPE], (name, op, path)
+            kinds.add(op)
+        else:
+            # the loss and metric means and the guard's agreement
+            assert not named and "step.optimizer" in parts, (name, op, path)
+    assert kinds == {"all-gather" if "allgather" in case else "all-reduce"}, kinds
+    # XLA may combine the dense mean's all-reduces with the optimizer's into
+    # one, named by one of them; the all-gathers of the payload stay apart
+    gathers = [(name, path) for name, op, path, _, _ in compiled_step
+               if op == "all-gather"]
+    assert ("allgather" in case) is bool(gathers), gathers
+    assert all(COLLECTIVE_SCOPE in components(p) for _, p in gathers), gathers
